@@ -11,7 +11,7 @@ the JSON is uploaded as a CI artifact).
   executor_*         threaded end-to-end scheduling overhead
   pipeline_dag_*     §9 DAG runtime: per-stage tuning vs global baseline
   device_dag_*       §11 device path: fused super-table walker vs per-stage
-                     launches (interpret mode)
+                     launches (interpreted off the TPU)
   pipeline_server_*  §10 serving runtime: fair-share vs FIFO on mixed jobs;
                      §14 open-loop admission front door; §15 preemptive
                      arbiter hit-rate + mid-flight migration bit-equality
@@ -68,20 +68,16 @@ def substrate_provenance() -> dict:
     """
     import platform
 
-    info = {
+    import jax
+
+    return {
         "host_cpu_count": os.cpu_count(),
         "platform": platform.platform(),
         "python": platform.python_version(),
+        "jax_backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "n_devices": jax.device_count(),
     }
-    try:
-        import jax
-        info["jax_backend"] = jax.default_backend()
-        info["device_kind"] = jax.devices()[0].device_kind
-        info["n_devices"] = jax.device_count()
-    except Exception as e:  # bench rows that never touch jax still stamp
-        info["jax_backend"] = f"unavailable ({type(e).__name__})"
-        info["device_kind"] = "unknown"
-    return info
 
 
 def row(name: str, us: float, derived: str = "") -> None:
@@ -296,7 +292,7 @@ def bench_pipeline_dag(quick: bool = False) -> None:
 
 def bench_device_dag(quick: bool = False) -> None:
     """Device-DAG rows (§11): the fused multi-stage Pallas walker vs one
-    launch per stage, on the linreg pipeline in interpret mode.
+    launch per stage, on the linreg pipeline (interpreted off the TPU).
 
     ``device_dag_linreg`` is the CI-gated row: ``equal=1`` asserts the
     fused super-table run reproduces the per-stage-launch results (and

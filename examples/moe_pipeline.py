@@ -88,16 +88,16 @@ def main() -> None:
     if args.tokens >= 384 and args.experts >= 32:
         assert on.resizes.get("experts", 0) >= 1, "skew should force a resize"
 
-    # 3. device walker path (Pallas interpret mode on CPU)
+    # 3. device walker path (Mosaic on a TPU, the Pallas interpreter elsewhere)
     if args.device:
         dlow = moe_device_lowering(low)
         from repro.vee.apps import run_device_dag
         t0 = time.perf_counter()
-        vals, _ = run_device_dag(dlow, "GSS", interpret=True)
+        vals, _ = run_device_dag(dlow, "GSS")
         dt = (time.perf_counter() - t0) * 1e3
-        ok = np.array_equal(dlow.finalize(vals), direct)
-        print(f"device walker:  {dt:.1f}ms  bit-equal={'yes' if ok else 'NO'}")
-        assert ok, "device combine != direct"
+        err = float(np.abs(dlow.finalize(vals) - direct).max())
+        print(f"device walker:  {dt:.1f}ms  max |device - direct| = {err:.2e}")
+        assert err <= 1e-5, "device combine != direct"
 
 
 if __name__ == "__main__":

@@ -305,7 +305,15 @@ def _merge_shard_slots(
     independent branches interleave. Readiness: elementwise = the producer
     tile with the same index was already emitted (same shard by
     row-alignment); full = the producer is fully emitted.
+
+    A producer with elementwise consumers also waits until every such
+    consumer has taken the producer's previous tile and has its barrier
+    (full) inputs complete: the walker keeps one output block per stage on
+    chip, so a consumer can read the producer's tile t only before the
+    producer moves on to its next tile.
     """
+    consumers = {n: [c for c in names if (n, "elementwise") in deps[c]]
+                 for n in names}
     per_shard: list[list[tuple[int, int, int]]] = [[] for _ in range(n_shards)]
     for shard in range(n_shards):
         tiles = {
@@ -316,16 +324,21 @@ def _merge_shard_slots(
         ptr = {n: 0 for n in names}
         emitted = {n: set() for n in names}
 
+        def barriers_done(n: str) -> bool:
+            return all(ptr[p] == len(tiles[p]) for p, kind in deps[n]
+                       if kind == "full")
+
         def ready(n: str) -> bool:
             """Is stage ``n``'s next tile runnable on this shard?"""
             t = tiles[n][ptr[n]]
-            for p, kind in deps[n]:
-                if kind == "full":
-                    if ptr[p] < len(tiles[p]):
-                        return False
-                elif t not in emitted[p]:
-                    return False
-            return True
+            if not barriers_done(n):
+                return False
+            if any(t not in emitted[p] for p, kind in deps[n]
+                   if kind == "elementwise"):
+                return False
+            # consumer tile lists are the producer's, in the same order
+            return all(ptr[c] >= ptr[n] and barriers_done(c)
+                       for c in consumers[n])
 
         total = sum(len(v) for v in tiles.values())
         cursor = 0

@@ -34,7 +34,11 @@ the runtime lock and fold at ``record()``; a preempted worker never
 abandons a chunk mid-op, so the checkpoint sees each chunk either fully
 folded or still queued — never a torn partial. Resuming replays the
 queued remainder through the same ascending fold the unpreempted run
-uses, so the float association is identical.
+uses, so the float association is identical. Across substrates
+(``migrate_to_device``, ``run_device_prefix``) the association is kept
+too, so the result matches a never-preempted run as closely as host ops
+and walker bodies agree: bit for bit where they run the same arithmetic
+(the CPU), to float32 rounding on a TPU (vee.apps.DeviceLowering).
 """
 
 from __future__ import annotations
@@ -485,8 +489,7 @@ def _tile_sets(ck: JobCheckpoint) -> dict[str, set[int]]:
     return pending
 
 
-def migrate_to_device(ck: JobCheckpoint, lowering, interpret: bool = True,
-                      tracer=None):
+def migrate_to_device(ck: JobCheckpoint, lowering, tracer=None):
     """Resume a host checkpoint on the device walker, bit-equal.
 
     ``lowering`` is the vee ``DeviceLowering`` whose tile-unit host DAG
@@ -621,8 +624,7 @@ def migrate_to_device(ck: JobCheckpoint, lowering, interpret: bool = True,
     if len(new_table):
         scaled = new_table.copy()
         scaled[:, 1:] *= tile
-        walked = dag_walk(walk_stages, operands, values, scaled, tile,
-                          interpret=interpret)
+        walked = dag_walk(walk_stages, operands, values, scaled, tile)
     else:
         walked = {}
 
@@ -651,7 +653,7 @@ def migrate_to_device(ck: JobCheckpoint, lowering, interpret: bool = True,
     return final
 
 
-def run_device_prefix(lowering, n_slots: int, interpret: bool = True):
+def run_device_prefix(lowering, n_slots: int):
     """Run the first ``n_slots`` super-table slots, then checkpoint.
 
     The device side of mid-flight migration: freeze the lowering with
@@ -682,7 +684,7 @@ def run_device_prefix(lowering, n_slots: int, interpret: bool = True):
         scaled = prefix.copy()
         scaled[:, 1:] *= tile
         walked = dag_walk(lowering.stages, lowering.operands, lowering.values,
-                          scaled, tile, interpret=interpret)
+                          scaled, tile)
     else:
         walked = {}
 

@@ -13,14 +13,18 @@ removed. This module executes a whole pipeline-DAG super-table
 * the prefetched stage id selects the stage body with ``pl.when`` — each
   ``WalkStage`` contributes a body over refs (cc_propagate's
   ``propagate_body`` is the single-stage special case);
-* block index maps read the slot's row range from the table, so every
-  operand/output block follows the schedule (clamped for slots that
-  belong to other stages — those fetches are untouched and written back
-  verbatim);
-* a consumer stage reads its producer's OUTPUT ref directly: because
-  build_dag_tables orders a consumer tile's slot after its producer
-  tile's slot, the producer block is already final when fetched — the
-  trace-time analogue of §9 inter-stage chunk streaming.
+* operand block index maps read the slot's row range from the table
+  (clamped), so every input block follows the schedule;
+* a TPU never reads an output block back from HBM: it writes a block
+  back when the block index moves on, and a block it has left must not
+  be visited again. So a concat stage's output block follows its OWN
+  slots only (the table carries, per slot, every stage's latest start),
+  and another stage's slot neither moves it nor writes it back;
+* a consumer stage reads its producer's OUTPUT ref directly, which holds
+  the producer's latest tile: build_dag_tables lets a producer move to
+  its next tile only after its elementwise consumers took the current
+  one (``dag_walk`` checks this on the host) — the trace-time analogue
+  of §9 inter-stage chunk streaming.
 
 Supported edge reads: ``rows`` (elementwise dep on a ``concat`` producer
 — the consumer's row tile of the producer's output) and ``full`` (full
@@ -44,7 +48,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["WalkOperand", "WalkStage", "WalkCtx", "dag_walk",
+from .mode import pallas_call
+
+__all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
            "dag_walk_stagewise", "dag_walk_sharded",
            "device_table_cache_stats", "clear_device_table_cache"]
 
@@ -76,8 +82,42 @@ def clear_device_table_cache() -> None:
     _DEVICE_TABLE_STATS["misses"] = 0
 
 
+def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
+    """The walker's prefetched table: ``(n_slots, 3 + n_stages) int32``.
+
+    Columns 0-2 are the super-table's ``(stage, start, size)``; column
+    ``3 + k`` is stage k's current start at each slot — the start of its
+    latest slot so far, or of its first slot before it has run (0 for a
+    stage without slots). Raises when a consumer's ``rows`` read of a
+    walker stage would find the producer's output block on another tile.
+    """
+    n_slots = len(table)
+    live = table[:, 2] > 0
+    cur = np.zeros((n_slots, len(stages)), np.int32)
+    for k in range(len(stages)):
+        mine = np.flatnonzero(live & (table[:, 0] == k))
+        if len(mine):
+            latest = np.searchsorted(mine, np.arange(n_slots), side="right") - 1
+            cur[:, k] = table[mine[np.maximum(latest, 0)], 1]
+    ids = {s.name: k for k, s in enumerate(stages)}
+    for k, s in enumerate(stages):
+        mine = live & (table[:, 0] == k)
+        for prod, kind in s.reads:
+            if kind != "rows" or prod not in ids:
+                continue
+            stale = mine & (cur[:, ids[prod]] != table[:, 1])
+            if stale.any():
+                i = int(np.flatnonzero(stale)[0])
+                raise ValueError(
+                    f"stage {s.name!r} reads rows {int(table[i, 1])}.. of "
+                    f"{prod!r} at slot {i}, after {prod!r} moved on to rows "
+                    f"{int(cur[i, ids[prod]])}..; order each consumer tile "
+                    "before its producer's next tile (build_dag_tables does)")
+    return np.ascontiguousarray(np.concatenate([table, cur], axis=1))
+
+
 def _device_table(table: np.ndarray, key: tuple | None) -> jax.Array:
-    """Device-resident copy of a host super-table.
+    """Device-resident copy of a walker table (``_walk_table``), flattened.
 
     Unkeyed: a plain ``jax.device_put`` — async dispatch, so issuing it
     for shard ``s+1`` before walking shard ``s`` double-buffers the
@@ -88,6 +128,7 @@ def _device_table(table: np.ndarray, key: tuple | None) -> jax.Array:
     read-only), so ``may_alias`` lets same-device backends alias the
     host buffer instead of copying.
     """
+    table = table.reshape(-1)
     if key is None:
         return jax.device_put(table, may_alias=True)
     ck = (key, table.shape, table.tobytes())
@@ -170,15 +211,21 @@ class WalkCtx:
 
 
 def _index_map(block: tuple[int, ...], kinds: tuple[str, ...],
-               shape: tuple[int, ...]):
-    """Block index map for one buffer: slot row tile / inner / constant."""
+               shape: tuple[int, ...], width: int, start_col: int = 1):
+    """Block index map for one buffer: row tile / inner / constant.
+
+    ``row`` axes follow the row start in column ``start_col`` of the
+    flattened ``width``-wide walker table: the slot's own start (1) for
+    operands, the stage's current start (3 + k) for stage k's output.
+    """
     nb = [max(1, shape[a] // block[a]) for a in range(len(block))]
 
     def imap(i, j, tbl):
         out = []
         for a, kind in enumerate(kinds):
             if kind == "row":
-                out.append(jnp.minimum(tbl[i, 1] // block[a], nb[a] - 1))
+                out.append(jnp.minimum(tbl[width * i + start_col] // block[a],
+                                       nb[a] - 1))
             elif kind == "inner":
                 out.append(jnp.minimum(j, nb[a] - 1))
             else:
@@ -215,13 +262,99 @@ def _out_spec(stage: WalkStage, tile: int) -> tuple[tuple[int, ...], tuple[str, 
     return tuple(stage.out_shape), ("zero",) * len(stage.out_shape)
 
 
+STAMP_ROWS = 8     # slots per stamp-buffer block (one (8, 128) int32 tile)
+STAMP_LANES = 128
+
+
+def walk_call(
+    stages: list[WalkStage],
+    operands: list[WalkOperand],
+    shapes: dict[str, tuple[int, ...]],
+    n_slots: int,
+    tile: int,
+    stamp: bool = False,
+) -> Callable:
+    """Build the fused walker's kernel call for a super-table of ``n_slots``.
+
+    Needs only shapes (``shapes`` maps operand names to array shapes), so
+    the kernel can be lowered for a described device that holds no data.
+    Returns ``f(table, *operand_values)``, where ``table`` is the walker
+    table (``_walk_table``) flattened to 1-D int32 (scalar memory pads a
+    2-D table's rows to 128 lanes), giving one output per stage, in
+    ``stages`` order, plus the stamp buffer when ``stamp``: a
+    ``(ceil(n_slots / 8) * 8, 128) int32`` array whose row ``slot`` holds
+    ``(stage_id, start, size, slot)`` in its first four lanes. Eight slots
+    share one aligned ``(8, 128)`` block; each grid step rewrites only its
+    own row, so a block is complete when the walk leaves it.
+    """
+    n_inner = max(s.inner for s in stages)
+    width = 3 + len(stages)
+    in_specs = [pl.BlockSpec(op.block, _index_map(op.block, op.index,
+                                                  shapes[op.name], width))
+                for op in operands]
+    out_specs, out_shapes = [], []
+    for k, s in enumerate(stages):
+        block, kinds = _out_spec(s, tile)
+        out_specs.append(pl.BlockSpec(block, _index_map(
+            block, kinds, s.out_shape, width, start_col=3 + k)))
+        out_shapes.append(jax.ShapeDtypeStruct(tuple(s.out_shape), s.out_dtype))
+    if stamp:
+        n_pad = -(-n_slots // STAMP_ROWS) * STAMP_ROWS
+        out_specs.append(pl.BlockSpec((STAMP_ROWS, STAMP_LANES),
+                                      lambda i, j, tbl: (i // STAMP_ROWS, 0)))
+        out_shapes.append(jax.ShapeDtypeStruct((n_pad, STAMP_LANES), jnp.int32))
+
+    n_ops = len(operands)
+
+    def kernel(tbl_ref, *refs):
+        ins = {op.name: refs[k] for k, op in enumerate(operands)}
+        outs = {s.name: refs[n_ops + k] for k, s in enumerate(stages)}
+        i = pl.program_id(0)
+        j = pl.program_id(1)
+        sid = tbl_ref[width * i]
+        start = tbl_ref[width * i + 1]
+        size = tbl_ref[width * i + 2]
+
+        @pl.when((i == 0) & (j == 0))
+        def _init_sums():
+            for s in stages:
+                if s.combine == "sum":
+                    outs[s.name][...] = jnp.zeros(s.out_shape, s.out_dtype)
+
+        if stamp:
+            # per-slot event stamp: idempotent across inner steps (each
+            # writes the same row), read back post-walk as tracer spans
+            st_ref = refs[n_ops + len(stages)]
+            shape = (STAMP_ROWS, STAMP_LANES)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            event = jnp.where(lane == 0, sid, jnp.where(
+                lane == 1, start, jnp.where(lane == 2, size, i)))
+            st_ref[...] = jnp.where(row == i % STAMP_ROWS, event, st_ref[...])
+
+        for k, s in enumerate(stages):
+            def run(s=s):
+                stage_ins = {n: ins[n] for n in s.operands}
+                for prod, _kind in s.reads:
+                    stage_ins[prod] = outs[prod] if prod in outs else ins[prod]
+                s.body(WalkCtx(i, j, start, size), stage_ins, outs[s.name])
+            pl.when((sid == k) & (j < s.inner) & (size > 0))(run)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_slots, n_inner),
+        in_specs=in_specs,
+        out_specs=out_specs,
+    )
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shapes)
+
+
 def dag_walk(
     stages: list[WalkStage],
     operands: list[WalkOperand],
     values: dict[str, Any],
     table: np.ndarray,
     tile: int,
-    interpret: bool = True,
     table_key: tuple | None = None,
     _dev_table: jax.Array | None = None,
     stamp: bool = False,
@@ -240,7 +373,7 @@ def dag_walk(
     ``stamp=True`` adds an ``(n_slots, 4) int32`` event buffer output —
     each slot's grid step writes ``(stage_id, start, size, slot)`` into
     its own row (idempotent across inner steps, so the walk's own cost
-    is one int32 row store per slot). The buffer is read back post-walk
+    is one small row store per slot). The buffer is read back post-walk
     by ``core.device_schedule.device_walk_spans`` and turned into tracer
     spans; the return becomes ``({stage: out}, stamps)``.
     """
@@ -251,78 +384,21 @@ def dag_walk(
     if len(by_name) != len(stages):
         raise ValueError("duplicate stage names")
     n_slots = len(table)
-    n_inner = max(s.inner for s in stages)
     if n_slots == 0:
         empty = {s.name: jnp.zeros(s.out_shape, s.out_dtype) for s in stages}
         if stamp:
             return empty, np.zeros((0, 4), dtype=np.int32)
         return empty
 
-    in_specs = []
-    for op in operands:
-        arr = values[op.name]
-        in_specs.append(pl.BlockSpec(op.block,
-                                     _index_map(op.block, op.index, arr.shape)))
-    out_specs, out_shapes = [], []
-    for s in stages:
-        block, kinds = _out_spec(s, tile)
-        out_specs.append(pl.BlockSpec(block, _index_map(block, kinds, s.out_shape)))
-        out_shapes.append(jax.ShapeDtypeStruct(tuple(s.out_shape), s.out_dtype))
-    if stamp:
-        out_specs.append(pl.BlockSpec((1, 4), lambda i, j, tbl: (i, 0)))
-        out_shapes.append(jax.ShapeDtypeStruct((n_slots, 4), jnp.int32))
-
-    n_ops = len(operands)
-
-    def kernel(tbl_ref, *refs):
-        ins = {op.name: refs[k] for k, op in enumerate(operands)}
-        outs = {s.name: refs[n_ops + k] for k, s in enumerate(stages)}
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        sid = tbl_ref[i, 0]
-        start = tbl_ref[i, 1]
-        size = tbl_ref[i, 2]
-
-        @pl.when((i == 0) & (j == 0))
-        def _init_sums():
-            for s in stages:
-                if s.combine == "sum":
-                    outs[s.name][...] = jnp.zeros(s.out_shape, s.out_dtype)
-
-        if stamp:
-            # per-slot event stamp: idempotent across inner steps (each
-            # writes the same row), read back post-walk as tracer spans
-            st_ref = refs[n_ops + len(stages)]
-            st_ref[0, 0] = sid
-            st_ref[0, 1] = start
-            st_ref[0, 2] = size
-            st_ref[0, 3] = i
-
-        for k, s in enumerate(stages):
-            def run(s=s):
-                stage_ins = {n: ins[n] for n in s.operands}
-                for prod, _kind in s.reads:
-                    stage_ins[prod] = outs[prod] if prod in outs else ins[prod]
-                s.body(WalkCtx(i, j, start, size), stage_ins, outs[s.name])
-            pl.when((sid == k) & (j < s.inner) & (size > 0))(run)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_slots, n_inner),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
+    call = walk_call(stages, operands,
+                     {op.name: tuple(values[op.name].shape) for op in operands},
+                     n_slots, tile, stamp=stamp)
     tbl_dev = _dev_table if _dev_table is not None \
-        else _device_table(table, table_key)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(tbl_dev, *[values[op.name] for op in operands])
+        else _device_table(_walk_table(table, stages), table_key)
+    out = call(tbl_dev, *[values[op.name] for op in operands])
     named = {s.name: o for s, o in zip(stages, out)}
     if stamp:
-        return named, np.asarray(out[len(stages)])
+        return named, np.asarray(out[len(stages)])[:n_slots, :4]
     return named
 
 
@@ -332,7 +408,6 @@ def dag_walk_stagewise(
     values: dict[str, Any],
     table: np.ndarray,
     tile: int,
-    interpret: bool = True,
 ) -> dict[str, jax.Array]:
     """One launch per stage: the pre-fusion baseline.
 
@@ -356,8 +431,7 @@ def dag_walk_stagewise(
             stage_vals[prod] = results[prod]
         solo = dataclasses.replace(
             s, operands=s.operands + tuple(p for p, _ in s.reads), reads=())
-        out = dag_walk([solo], stage_ops, stage_vals, sub, tile,
-                       interpret=interpret)
+        out = dag_walk([solo], stage_ops, stage_vals, sub, tile)
         results[s.name] = out[s.name]
     return results
 
@@ -368,7 +442,6 @@ def dag_walk_sharded(
     values: dict[str, Any],
     tables: np.ndarray,
     tile: int,
-    interpret: bool = True,
     table_key: tuple | None = None,
 ) -> dict[str, np.ndarray]:
     """Walk every shard's super-table and combine the per-shard outputs.
@@ -389,13 +462,15 @@ def dag_walk_sharded(
     n_shards = tables.shape[0]
     key = (lambda s: (table_key, s)) if table_key is not None \
         else (lambda s: None)
-    nxt = _device_table(tables[0], key(0)) if n_shards else None
+    def put(s):
+        return _device_table(_walk_table(tables[s], stages), key(s))
+
+    nxt = put(0) if n_shards else None
     shard_outs = []
     for s in range(n_shards):
-        cur, nxt = nxt, (_device_table(tables[s + 1], key(s + 1))
-                         if s + 1 < n_shards else None)
+        cur, nxt = nxt, (put(s + 1) if s + 1 < n_shards else None)
         shard_outs.append(dag_walk(stages, operands, values, tables[s], tile,
-                                   interpret=interpret, _dev_table=cur))
+                                   _dev_table=cur))
     combined: dict[str, np.ndarray] = {}
     for k, s in enumerate(stages):
         if s.combine == "sum":

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -58,11 +60,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, causal,
                     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "tile_q", "tile_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "tile_q", "tile_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, tile_q: int = 256, tile_k: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    causal: bool = True, tile_q: int = 256, tile_k: int = 512) -> jax.Array:
     """q,k,v: (B, H, S, dh) (same H — GQA is expanded by ops.py)."""
     b, h, s, dh = q.shape
     sk = k.shape[2]
@@ -76,7 +76,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     kernel = functools.partial(_kernel, causal=causal, tile_q=tile_q,
                                tile_k=tile_k, scale=scale)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(b * h, s // tile_q, sk // tile_k),
         in_specs=[
@@ -91,6 +91,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((tile_q,), jnp.float32),
             pltpu.VMEM((tile_q, dh), jnp.float32),
         ],
-        interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, dh)

@@ -1,8 +1,8 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-On this CPU container every kernel runs with interpret=True (the Pallas
-interpreter executes the kernel body exactly); on real TPU pass
-``interpret=False`` (the model selects via ``cfg.use_pallas``).
+Each kernel compiles through Mosaic on a TPU and runs in Pallas's TPU
+interpret mode on any other backend (kernels/mode.py); the wrappers take
+no mode argument.
 """
 
 from __future__ import annotations
@@ -39,16 +39,15 @@ def dls_tile_schedule(technique: str, n_rows: int, tile_r: int,
 
 
 def cc_step(G, c, technique: str = "MFSC", n_workers: int = 8,
-            tile_r: int = 256, tile_c: int = 1024, interpret: bool = True):
+            tile_r: int = 256, tile_c: int = 1024):
     """One scheduler-driven CC propagation step (paper Listing 1 kernel)."""
     schedule = jnp.asarray(dls_tile_schedule(technique, G.shape[0], tile_r,
                                              n_workers))
-    return cc_propagate(G, c, schedule, tile_r=tile_r, tile_c=tile_c,
-                        interpret=interpret)
+    return cc_propagate(G, c, schedule, tile_r=tile_r, tile_c=tile_c)
 
 
 def attention(q, k, v, causal: bool = True, tile_q: int = 256,
-              tile_k: int = 512, interpret: bool = True):
+              tile_k: int = 512):
     """GQA-aware wrapper: expands KV heads then calls the flash kernel."""
     b, h, s, dh = q.shape
     kv = k.shape[1]
@@ -57,12 +56,12 @@ def attention(q, k, v, causal: bool = True, tile_q: int = 256,
         k = jnp.repeat(k, g, axis=1)
         v = jnp.repeat(v, g, axis=1)
     return flash_attention(q, k, v, causal=causal, tile_q=tile_q,
-                           tile_k=tile_k, interpret=interpret)
+                           tile_k=tile_k)
 
 
-def mamba2_chunk_scan(x, dt, A, B, C, D, chunk: int = 128, interpret: bool = True):
-    return ssm_scan(x, dt, A, B, C, D, chunk=chunk, interpret=interpret)
+def mamba2_chunk_scan(x, dt, A, B, C, D, chunk: int = 128):
+    return ssm_scan(x, dt, A, B, C, D, chunk=chunk)
 
 
-def wkv6(r, k, v, logw, u, chunk: int = 64, interpret: bool = True):
-    return rwkv6_scan(r, k, v, logw, u, chunk=chunk, interpret=interpret)
+def wkv6(r, k, v, logw, u, chunk: int = 64):
+    return rwkv6_scan(r, k, v, logw, u, chunk=chunk)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import pallas_call
+
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *, q):
     ci = pl.program_id(1)
@@ -66,9 +68,9 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *, q):
         kw.T, v, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
-               u: jax.Array, chunk: int = 64, interpret: bool = True) -> jax.Array:
+               u: jax.Array, chunk: int = 64) -> jax.Array:
     """r,k,v,logw: (Bt, H, S, dh); u: (H, dh). Returns (Bt, H, S, dh) fp32."""
     bt, h, s, dh = r.shape
     q = min(chunk, s)
@@ -81,7 +83,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
     uf = jnp.broadcast_to(u[None], (bt, h, dh)).reshape(bt * h, 1, dh)
 
     kernel = functools.partial(_kernel, q=q)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(bt * h, nc),
         in_specs=[
@@ -94,6 +96,5 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         out_specs=pl.BlockSpec((1, q, dh), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bt * h, s, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        interpret=interpret,
     )(rf, kf, vf, lwf, uf)
     return out.reshape(bt, h, s, dh)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import pallas_call
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *, q):
     ci = pl.program_id(1)
@@ -53,10 +55,9 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *, q):
     state_scr[...] = state * jnp.exp(cum[-1]) + upd
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-             C: jax.Array, D: jax.Array, chunk: int = 128,
-             interpret: bool = True) -> jax.Array:
+             C: jax.Array, D: jax.Array, chunk: int = 128) -> jax.Array:
     """x: (Bt, S, H, dh); dt: (Bt, S, H); A,D: (H,); B,C: (Bt, S, N)."""
     bt, s, h, dh = x.shape
     n = B.shape[-1]
@@ -72,7 +73,7 @@ def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     cf = jnp.broadcast_to(C[:, None], (bt, h, s, n)).reshape(bt * h, s, n)
 
     kernel = functools.partial(_kernel, q=q)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(bt * h, nc),
         in_specs=[
@@ -85,7 +86,6 @@ def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         out_specs=pl.BlockSpec((1, q, dh), lambda i, c_: (i, c_, 0)),
         out_shape=jax.ShapeDtypeStruct((bt * h, s, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dh, n), jnp.float32)],
-        interpret=interpret,
     )(xf, dtf, af, bf, cf)
     y = out.reshape(bt, h, s, dh).transpose(0, 2, 1, 3)
     return y + D[None, None, :, None] * x.astype(jnp.float32)

@@ -11,29 +11,18 @@ stay pod-local (DESIGN.md §5).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_mesh_compat", "make_production_mesh", "make_host_mesh"]
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    Newer jax wants explicit Auto axis_types (meshes default to different
-    semantics); older releases (<= 0.4.x) don't have AxisType at all.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+__all__ = ["make_production_mesh", "make_host_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over available host devices (tests, examples)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    """Small (data, model) mesh over available host devices (tests, examples)."""
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -160,8 +160,15 @@ def serve_openloop(args) -> None:
     _dump_telemetry(args, tracer, metrics)
 
 
-def serve_lm(args) -> None:
-    """LM continuous batching with DLS-technique admission chunks."""
+def serve_lm(args) -> dict:
+    """LM continuous batching with DLS-technique admission chunks.
+
+    Returns what was generated: the seeded ``prompts`` ``(requests,
+    prompt_len)``, ``tokens`` ``(requests, gen_len)`` (the prefill's pick,
+    then one per decode step) and ``first_decode_logits`` ``(requests,
+    padded_vocab)`` float32, the logits of each request's first decode step
+    (None when ``gen_len`` is 1 and nothing is decoded).
+    """
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -184,6 +191,7 @@ def serve_lm(args) -> None:
                             dtype=np.int32) for _ in range(args.requests)]
     part = make_partitioner(args.technique, args.requests, args.slots)
 
+    tokens, first_logits = [], []
     served, t0 = 0, time.perf_counter()
     while served < args.requests:
         n = min(part.next_chunk() or 1, args.requests - served)
@@ -192,22 +200,37 @@ def serve_lm(args) -> None:
         pad = (-len(reqs)) % args.slots
         toks = np.stack(reqs + [reqs[-1]] * pad)
         for i in range(0, len(toks), args.slots):
+            real = min(args.slots, len(reqs) - i)  # pad rows come last
             sl = jnp.asarray(toks[i:i + args.slots])
             cache = model.init_cache(sl.shape[0], s_max)
             logits, cache = prefill(params, {"tokens": sl}, cache)
             tok = jnp.argmax(logits[:, -1], -1)[:, None]
+            out = [tok[:real]]
             for t in range(args.gen_len - 1):
                 logits, cache = decode(params, tok, cache,
                                        jnp.int32(args.prompt_len + t))
+                if t == 0:
+                    first_logits.append(logits[:real, 0])
                 tok = jnp.argmax(logits[:, 0], -1)[:, None]
+                out.append(tok[:real])
+            tokens.append(jnp.concatenate(out, axis=1))
     dt = time.perf_counter() - t0
     print(f"[serve] {args.requests} requests x {args.gen_len} tokens in "
           f"{dt:.1f}s ({args.requests * args.gen_len / dt:.1f} tok/s)",
           flush=True)
+    return {"prompts": np.stack(backlog),
+            "tokens": np.concatenate([np.asarray(t) for t in tokens]),
+            "first_decode_logits": np.concatenate(
+                [np.asarray(x, np.float32) for x in first_logits])
+            if first_logits else None}
 
 
-def main() -> None:
-    """Entry point: dispatch to LM serving or multi-tenant pipeline serving."""
+def main(argv: list[str] | None = None):
+    """Entry point: dispatch to LM serving or multi-tenant pipeline serving.
+
+    ``argv`` defaults to the command line; returns what ``serve_lm``
+    generated in ``--mode lm``.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["lm", "pipelines", "openloop"],
                     default="lm")
@@ -239,13 +262,16 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None, metavar="METRICS.json",
                     help="write a metrics snapshot as JSON plus a .prom "
                          "Prometheus-text sibling (pipelines/openloop modes)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "pipelines":
         serve_pipelines(args)
     elif args.mode == "openloop":
         serve_openloop(args)
     else:
-        serve_lm(args)
+        return serve_lm(args)
 
 
 if __name__ == "__main__":

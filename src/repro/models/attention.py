@@ -25,7 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..runtime.pspec import shard, shard_map_compat
+from ..runtime.pspec import shard
 from .layers import Params, apply_rope, dense, he_init, rms_norm
 
 NEG_INF = -1e30
@@ -289,7 +289,7 @@ def _seq_sharded_attention(q, k, v, cfg, rules):
         return chunked_attention(q_loc, k_full, v_full, causal=True,
                                  q_block=qb, kv_block=kb, kv_offset=off)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, check_vma=False,
         in_specs=(P(b_axes, None, "model", None),
                   P(b_axes, None, None, None), P(b_axes, None, None, None)),

@@ -25,7 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..runtime.pspec import current_rules, shard, shard_map_compat
+from ..runtime.pspec import current_rules, shard
 from .layers import Params, dense, he_init, mlp, init_mlp
 
 NEG_INF = -1e30
@@ -153,7 +153,7 @@ def moe_block(params: Params, x: jax.Array, cfg: Any) -> tuple[jax.Array, jax.Ar
             return y, aux
 
         routed_params = {"router": params["router"], "experts": params["experts"]}
-        y, aux = shard_map_compat(
+        y, aux = jax.shard_map(
             body, mesh=mesh, check_vma=False,
             in_specs=(param_specs, P(batch_axes, None, None)),
             out_specs=(P(batch_axes, None, None), P()),

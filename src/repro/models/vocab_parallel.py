@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..runtime.pspec import current_rules, shard_map_compat
+from ..runtime.pspec import current_rules
 
 NEG_INF = -1e30
 
@@ -59,7 +59,7 @@ def vp_embed(table: jax.Array, tokens: jax.Array, batch_axes) -> jax.Array:
         out = jnp.where(in_range[..., None], out, 0)
         return jax.lax.psum(out, "model")
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, check_vma=False,
         in_specs=(P("model", None), P(batch_axes, None)),
         out_specs=P(batch_axes, None, None),
@@ -109,7 +109,7 @@ def vp_cross_entropy(logits: jax.Array, labels: jax.Array, vocab_size: int,
             cnt = jax.lax.psum(cnt, batch_axes)
         return tot / jnp.maximum(cnt, 1)
 
-    return shard_map_compat(
+    return jax.shard_map(
         # remat: backward recomputes the f32 CE intermediates from the bf16
         # logits instead of saving ~4 full-size f32 buffers per device.
         jax.checkpoint(body), mesh=mesh, check_vma=False,

@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
     "axis_rules", "current_rules", "logical_spec", "shard", "named_sharding",
-    "shard_map_compat",
     "AxisRules",
 ]
 
@@ -84,17 +83,3 @@ def shard(x: jax.Array, *names: str | None) -> jax.Array:
     if s is None:
         return x
     return jax.lax.with_sharding_constraint(x, s)
-
-
-def shard_map_compat(body, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)

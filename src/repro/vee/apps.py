@@ -43,6 +43,7 @@ __all__ = [
     "cc_iteration_dag", "connected_components_dag", "linreg_dag",
     "linear_regression_dag", "recommendation_dag",
     "recommendation_pipeline", "recommendation_oracle",
+    "recommendation_regret",
     "linear_regression_online", "recommendation_online",
     "DeviceLowering", "run_device_dag", "linreg_device_lowering",
     "linear_regression_device", "recommendation_device_lowering",
@@ -344,9 +345,7 @@ def recommendation_dag(
     shared pool; ``scores`` consumes item_norms in full and user_bias
     elementwise and emits each user's top item.
     """
-    rng = np.random.default_rng(seed)
-    R = rng.uniform(0.0, 1.0, size=(n_users, n_items))
-    R *= rng.uniform(size=(n_users, n_items)) < density
+    R = _ratings(n_users, n_items, density, seed)
 
     item_norms = Stage(
         "item_norms", n_users,
@@ -385,15 +384,36 @@ def recommendation_pipeline(
     return res.values["scores"], res
 
 
-def recommendation_oracle(n_users: int, n_items: int, density: float = 0.3,
-                          seed: int = 0) -> np.ndarray:
-    """Serial numpy oracle for recommendation_pipeline."""
+def _ratings(n_users: int, n_items: int, density: float, seed: int) -> np.ndarray:
+    """The seeded float64 ratings matrix every recommendation path shares."""
     rng = np.random.default_rng(seed)
     R = rng.uniform(0.0, 1.0, size=(n_users, n_items))
     R *= rng.uniform(size=(n_users, n_items)) < density
+    return R
+
+
+def recommendation_oracle(n_users: int, n_items: int, density: float = 0.3,
+                          seed: int = 0) -> np.ndarray:
+    """Serial numpy oracle for recommendation_pipeline."""
+    R = _ratings(n_users, n_items, density, seed)
     norms = np.sqrt((R ** 2).sum(axis=0)) + 1e-9
     bias = R.mean(axis=1)
     return np.argmax(R / norms - bias[:, None], axis=1)
+
+
+def recommendation_regret(items, n_users: int, n_items: int,
+                          density: float = 0.3, seed: int = 0) -> np.ndarray:
+    """Per user, how far the float64 score of ``items`` falls below the best.
+
+    Scores are ``R / item_norms - user_bias`` as in recommendation_oracle;
+    a user's bias is common to all their items, so the regret is that of
+    ``R / item_norms``. It is zero where ``items`` holds a best item, and
+    small where a float32 path broke a near-tie the other way.
+    """
+    R = _ratings(n_users, n_items, density, seed)
+    R /= np.sqrt((R ** 2).sum(axis=0)) + 1e-9
+    items = np.asarray(items).reshape(-1)
+    return R.max(axis=1) - R[np.arange(n_users), items]
 
 
 # ---------------------------------------------------------------------------
@@ -407,20 +427,20 @@ class DeviceLowering:
 
     ``dag`` is a host PipelineDAG in TILE units (one task row = one
     device row tile, so any host technique's chunks stay tile-aligned)
-    whose ops do the SAME per-tile float32 jnp math as the device
+    whose ops run the SAME per-tile float32 jnp functions as the device
     ``stages`` (kernels/dag_walk.py WalkStage specs over ``operands`` /
-    ``values``, in row space). Matrix products are written as
-    broadcast-multiply + ``sum(axis=0)`` in both: XLA fuses ``dot``
-    differently inside a kernel than eagerly (different summation order),
-    while plain reductions are fusion-stable — the bit-wise equality the
-    device tests assert depends on it. Host concat values are therefore
+    ``values``, in row space). Host concat values are
     ``(n_tiles, tile, ...)``; ``reshape(-1, ...)`` recovers row space.
 
+    Host ops run those functions eagerly through XLA, the walker runs
+    them inside one kernel (Mosaic on a TPU, interpreted elsewhere),
+    so the two agree within float32 rounding, not bit for bit: tests
+    hold both to the float64 numpy oracle within written tolerances.
     For sum stages the walker accumulates in flat ascending tile order
-    (any technique, one shard); the host matches it bit-wise when run
-    with ``technique="SS"`` (one-tile chunks) and ``n_workers=1`` —
-    coarser host chunks re-associate the float sum. ``finalize`` maps
-    stage values to the pipeline's answer (e.g. the linreg solve).
+    (any technique, one shard); the host folds in the same order when run
+    with ``technique="SS"`` (one-tile chunks) and ``n_workers=1``.
+    ``finalize`` maps stage values to the pipeline's answer (e.g. the
+    linreg solve).
     """
 
     dag: PipelineDAG
@@ -438,7 +458,6 @@ def run_device_dag(
     n_workers: int | None = None,
     chunk_costs: dict | None = None,
     seed: int = 0,
-    interpret: bool = True,
     stagewise: bool = False,
 ):
     """Execute a DeviceLowering end-to-end on the device-DAG path.
@@ -472,12 +491,10 @@ def run_device_dag(
         if n_shards != 1:
             raise ValueError("stagewise baseline runs single-shard")
         out = dag_walk_stagewise(lowering.stages, lowering.operands,
-                                 lowering.values, rows[0],
-                                 lowering.tile, interpret=interpret)
+                                 lowering.values, rows[0], lowering.tile)
     else:
         out = dag_walk_sharded(lowering.stages, lowering.operands,
                                lowering.values, rows, lowering.tile,
-                               interpret=interpret,
                                table_key=("devdag", lowering.tile, key))
     return {k: np.asarray(v) for k, v in out.items()}, ddt
 
@@ -562,12 +579,19 @@ def linreg_device_lowering(
 ) -> DeviceLowering:
     """Paper Listing 2 lowered for the fused device walker.
 
-    Two sum stages joined by a barrier edge: ``moments`` accumulates
-    column sums/squared sums; ``syrk_gemv`` standardizes each row tile
-    against the FULL moments (read straight from the walker's
-    accumulator ref mid-launch) and accumulates X1^T X1 | X1^T y.
-    Host ops and device bodies share the per-tile float32 jnp math.
+    The device operand is ``W = [X, 1, y]^T``, feature-major
+    ``(num_cols + 1, num_rows)`` float32: rows run along the lanes, so a
+    TPU holds it without padding 101 features out to 128 lanes and the
+    walker reads it without a relayout copy. Two sum stages joined by a
+    barrier edge: ``moments`` accumulates the sums and squared sums of
+    each feature (``(num_cols + 1, 2)``); ``syrk_gemv`` standardizes the X
+    features of each row tile against the FULL moments (read straight from
+    the walker's accumulator ref mid-launch), which turns the tile into
+    ``[X1, y]^T``, and accumulates the first d+1 rows of its Gram matrix,
+    ``[X1^T X1 | X1^T y]``, as one float32 matrix product.
+    Host ops and device bodies share the per-tile math.
     """
+    import jax
     import jax.numpy as jnp
 
     from ..kernels.dag_walk import WalkOperand, WalkStage
@@ -576,29 +600,36 @@ def linreg_device_lowering(
         raise ValueError(f"num_rows={num_rows} must be a multiple of tile={tile}")
     rng = np.random.default_rng(seed)
     XY = rng.uniform(0.0, 1.0, size=(num_rows, num_cols)).astype(np.float32)
-    X, y = XY[:, :-1], XY[:, -1:]
     d = num_cols - 1
     n = num_rows
     units = n // tile
+    W = np.empty((d + 2, n), np.float32)
+    W[:d] = XY[:, :d].T
+    W[d] = 1.0
+    W[d + 1] = XY[:, d]
+    del XY
 
-    def _moments_tile(Xb):
-        return jnp.stack([Xb.sum(axis=0), (Xb * Xb).sum(axis=0)])
+    def _moments_tile(Wb):
+        return Wb.sum(axis=1, keepdims=True), (Wb * Wb).sum(axis=1, keepdims=True)
 
-    def _syrk_tile(Xb, yb, M):
-        mean = M[0] / n
-        std = jnp.sqrt(jnp.maximum(M[1] / n - mean * mean, 0.0))
-        std = jnp.where(std == 0, jnp.ones_like(std), std)
-        X1 = jnp.concatenate(
-            [(Xb - mean) / std, jnp.ones((Xb.shape[0], 1), Xb.dtype)], axis=1)
-        # broadcast-multiply + reduce (not dot): fusion-stable bit-wise
-        A = (X1[:, :, None] * X1[:, None, :]).sum(axis=0)
-        b = (X1 * yb).sum(axis=0)
-        return jnp.concatenate([A, b[:, None]], axis=1)
+    def _syrk_tile(Wb, M):
+        mean = M[:, 0:1] / n
+        std = jnp.sqrt(jnp.maximum(M[:, 1:2] / n - mean * mean, 0.0))
+        is_x = jax.lax.broadcasted_iota(jnp.int32, mean.shape, 0) < d
+        mean = jnp.where(is_x, mean, 0.0)
+        std = jnp.where(is_x & (std != 0), std, 1.0)
+        Z = (Wb - mean) / std
+        gram = jax.lax.dot_general(  # Z Z^T on the matrix unit, full f32
+            Z, Z, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        return gram[:d + 1]
 
     def moments_op(inputs, s, z):
         acc = None
         for t in range(s, s + z):
-            v = _moments_tile(jnp.asarray(X[t * tile:(t + 1) * tile]))
+            v = jnp.concatenate(
+                _moments_tile(jnp.asarray(W[:, t * tile:(t + 1) * tile])), axis=1)
             acc = v if acc is None else acc + v
         return acc
 
@@ -606,8 +637,7 @@ def linreg_device_lowering(
         M = jnp.asarray(inputs["moments"])
         acc = None
         for t in range(s, s + z):
-            v = _syrk_tile(jnp.asarray(X[t * tile:(t + 1) * tile]),
-                           jnp.asarray(y[t * tile:(t + 1) * tile]), M)
+            v = _syrk_tile(jnp.asarray(W[:, t * tile:(t + 1) * tile]), M)
             acc = v if acc is None else acc + v
         return acc
 
@@ -618,23 +648,22 @@ def linreg_device_lowering(
     ])
 
     def moments_body(ctx, ins, out):
-        out[...] += _moments_tile(ins["X"][...])
+        sums, squares = _moments_tile(ins["W"][...])
+        out[:, 0:1] += sums
+        out[:, 1:2] += squares
 
     def syrk_body(ctx, ins, out):
-        out[...] += _syrk_tile(ins["X"][...], ins["y"][...], ins["moments"][...])
+        out[...] += _syrk_tile(ins["W"][...], ins["moments"][...])
 
     stages = [
-        WalkStage("moments", n, (2, d), jnp.float32, "sum", moments_body,
-                  operands=("X",)),
+        WalkStage("moments", n, (d + 2, 2), jnp.float32, "sum", moments_body,
+                  operands=("W",)),
         WalkStage("syrk_gemv", n, (d + 1, d + 2), jnp.float32, "sum",
-                  syrk_body, operands=("X", "y"),
+                  syrk_body, operands=("W",),
                   reads=(("moments", "full"),)),
     ]
-    operands = [
-        WalkOperand("X", (tile, d), ("row", "zero")),
-        WalkOperand("y", (tile, 1), ("row", "zero")),
-    ]
-    values = {"X": jnp.asarray(X), "y": jnp.asarray(y)}
+    operands = [WalkOperand("W", (d + 2, tile), ("zero", "row"))]
+    values = {"W": jnp.asarray(W)}
 
     def finalize(stage_values: dict) -> np.ndarray:
         Ab = np.asarray(stage_values["syrk_gemv"])
@@ -652,7 +681,6 @@ def linear_regression_device(
     stage_techniques: dict | str | None = None,
     lam: float = 0.001,
     seed: int = 1,
-    interpret: bool = True,
     stagewise: bool = False,
 ):
     """Paper Listing 2 end-to-end on the device-DAG path.
@@ -662,8 +690,7 @@ def linear_regression_device(
     """
     low = linreg_device_lowering(num_rows, num_cols, tile=tile, lam=lam,
                                  seed=seed)
-    vals, ddt = run_device_dag(low, stage_techniques, interpret=interpret,
-                               stagewise=stagewise)
+    vals, ddt = run_device_dag(low, stage_techniques, stagewise=stagewise)
     return low.finalize(vals), vals, ddt
 
 
@@ -676,31 +703,35 @@ def recommendation_device_lowering(
 ) -> DeviceLowering:
     """The two-branch recommendation DAG lowered for the fused walker.
 
-    ``item_norms`` (sum) and ``user_bias`` (concat) are independent;
-    ``scores`` reads item_norms in full (sum accumulator ref) and
-    user_bias elementwise (its own row tile of the concat buffer) —
-    exercising every edge kind the walker supports in one super-table.
+    ``item_norms`` (sum, ``(1, n_items)``) and ``user_bias`` (concat,
+    ``(n_users, 1)``) are independent; ``scores`` reads item_norms in full
+    (sum accumulator ref) and user_bias elementwise (its own row tile of
+    the concat buffer) — exercising every edge kind the walker supports in
+    one super-table. Per-user outputs are ``(n_users, 1)`` columns, so
+    row reductions stay on the axis they reduce to; ``scores`` picks the
+    first item of maximal score, as ``np.argmax`` does.
     """
+    import jax
     import jax.numpy as jnp
 
     from ..kernels.dag_walk import WalkOperand, WalkStage
 
     if n_users % tile:
         raise ValueError(f"n_users={n_users} must be a multiple of tile={tile}")
-    rng = np.random.default_rng(seed)
-    R = rng.uniform(0.0, 1.0, size=(n_users, n_items))
-    R = (R * (rng.uniform(size=(n_users, n_items)) < density)).astype(np.float32)
+    R = _ratings(n_users, n_items, density, seed).astype(np.float32)
     units = n_users // tile
 
     def _norms_tile(Rb):
-        return (Rb * Rb).sum(axis=0)
+        return (Rb * Rb).sum(axis=0, keepdims=True)
 
     def _bias_tile(Rb):
-        return Rb.mean(axis=1)
+        return Rb.mean(axis=1, keepdims=True)
 
     def _scores_tile(Rb, norms, bias):
-        return jnp.argmax(Rb / (jnp.sqrt(norms) + 1e-9) - bias[:, None],
-                          axis=1).astype(jnp.int32)
+        S = Rb / (jnp.sqrt(norms) + 1e-9) - bias
+        item = jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)
+        best = jnp.where(S == S.max(axis=1, keepdims=True), item, S.shape[1])
+        return best.min(axis=1, keepdims=True)
 
     def item_norms_op(inputs, s, z):
         acc = None
@@ -740,11 +771,11 @@ def recommendation_device_lowering(
                                 ins["user_bias"][...])
 
     stages = [
-        WalkStage("item_norms", n_users, (n_items,), jnp.float32, "sum",
+        WalkStage("item_norms", n_users, (1, n_items), jnp.float32, "sum",
                   item_norms_body, operands=("R",)),
-        WalkStage("user_bias", n_users, (n_users,), jnp.float32, "concat",
+        WalkStage("user_bias", n_users, (n_users, 1), jnp.float32, "concat",
                   user_bias_body, operands=("R",)),
-        WalkStage("scores", n_users, (n_users,), jnp.int32, "concat",
+        WalkStage("scores", n_users, (n_users, 1), jnp.int32, "concat",
                   scores_body, operands=("R",),
                   reads=(("item_norms", "full"), ("user_bias", "rows"))),
     ]
@@ -760,18 +791,17 @@ def recommendation_device(
     stage_techniques: dict | str | None = None,
     density: float = 0.3,
     seed: int = 0,
-    interpret: bool = True,
     stagewise: bool = False,
 ):
     """The recommendation pipeline end-to-end on the device-DAG path.
 
-    Returns (top_items, stage values, DeviceDagTables).
+    Returns (top_items, stage values, DeviceDagTables); top items are one
+    per user, in row order.
     """
     low = recommendation_device_lowering(n_users, n_items, tile=tile,
                                          density=density, seed=seed)
-    vals, ddt = run_device_dag(low, stage_techniques, interpret=interpret,
-                               stagewise=stagewise)
-    return vals["scores"], vals, ddt
+    vals, ddt = run_device_dag(low, stage_techniques, stagewise=stagewise)
+    return vals["scores"].reshape(-1), vals, ddt
 
 
 # ---------------------------------------------------------------------------
@@ -844,8 +874,7 @@ def _run_hetero(low: DeviceLowering, config, placement, costs,
     return res.values, res, placement
 
 
-def _run_migrated(low: DeviceLowering, cut: int, direction: str,
-                  interpret: bool = True) -> dict:
+def _run_migrated(low: DeviceLowering, cut: int, direction: str) -> dict:
     """Run ``low`` with one mid-flight substrate migration at chunk ``cut``.
 
     ``host_to_device`` starts the tile-unit DAG on the host pool
@@ -853,8 +882,9 @@ def _run_migrated(low: DeviceLowering, cut: int, direction: str,
     preempts after ``cut`` chunks, and re-lowers the checkpointed
     remainder onto the device walker. ``device_to_host`` drains ``cut``
     super-table slots on the walker, freezes the rest, and finishes on
-    the host pool. Either way the values are bit-equal to a
-    never-preempted run (DESIGN.md §15). Returns row-space values.
+    the host pool. Either way the values match a never-preempted run
+    (DESIGN.md §15; bit for bit where host ops and walker agree, see
+    ``DeviceLowering``). Returns row-space values.
     """
     from ..core.preempt import (PreemptiveRunner, migrate_to_device,
                                 resume_on_host, run_device_prefix)
@@ -865,9 +895,9 @@ def _run_migrated(low: DeviceLowering, cut: int, direction: str,
         res, ck = PreemptiveRunner(low.dag, cfg, preempt_after=cut).run()
         if ck is None:
             return {k: np.asarray(v) for k, v in res.values.items()}
-        return migrate_to_device(ck, low, interpret=interpret)
+        return migrate_to_device(ck, low)
     if direction == "device_to_host":
-        ck, _ = run_device_prefix(low, cut, interpret=interpret)
+        ck, _ = run_device_prefix(low, cut)
         fin = resume_on_host(ck, low.dag, cfg)
         return {k: np.asarray(v) for k, v in fin.values.items()}
     raise ValueError(f"unknown migration direction {direction!r}; expected "
@@ -882,17 +912,17 @@ def linear_regression_migrated(
     tile: int = 64,
     lam: float = 0.001,
     seed: int = 1,
-    interpret: bool = True,
 ) -> np.ndarray:
     """Listing 2 with a mid-flight substrate migration; returns beta.
 
     Convenience wrapper over ``_run_migrated`` for the linreg lowering —
-    the beta is bit-equal to both ``linear_regression_device`` and the
-    host-only executor, whichever substrate the job started on.
+    the beta matches both ``linear_regression_device`` and the host-only
+    executor, whichever substrate the job started on (as ``_run_migrated``
+    says).
     """
     low = linreg_device_lowering(num_rows, num_cols, tile=tile, lam=lam,
                                  seed=seed)
-    return low.finalize(_run_migrated(low, cut, direction, interpret))
+    return low.finalize(_run_migrated(low, cut, direction))
 
 
 def recommendation_migrated(
@@ -903,15 +933,15 @@ def recommendation_migrated(
     tile: int = 64,
     density: float = 0.3,
     seed: int = 0,
-    interpret: bool = True,
 ) -> np.ndarray:
     """The recommendation pipeline with one mid-flight migration.
 
-    Returns the scores in row space, bit-equal to the unmigrated runs.
+    Returns the scores in row space, matching the unmigrated runs (as
+    ``_run_migrated`` says).
     """
     low = recommendation_device_lowering(n_users, n_items, tile=tile,
                                          density=density, seed=seed)
-    values = _run_migrated(low, cut, direction, interpret)
+    values = _run_migrated(low, cut, direction)
     return np.asarray(values["scores"]).reshape(-1)
 
 

@@ -58,9 +58,9 @@ def test_elastic_restore_other_mesh(tmp_path):
     """Restore with shardings targeting a different (1x1) mesh layout."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_host_mesh
     save(tmp_path, 9, _tree())
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     shardings = {
         "params": {"w": NamedSharding(mesh, P("data", "model")),
                    "b": NamedSharding(mesh, P())},

@@ -22,20 +22,14 @@ from hlo_analysis import analyze_module, parse_hlo  # noqa: E402
 def scan_hlo():
     """Compile a scan of 8 matmuls on 4 host devices; return (hlo, xla_flops).
 
-    The artifact is generated in-fixture (no dry-run run needed); the mesh
-    construction and cost_analysis handling are version-portable (older jax
-    has no AxisType and returns a per-executable list from cost_analysis).
+    The artifact is generated in-fixture (no dry-run run needed).
     """
     script = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
-except ImportError:
-    mesh = jax.make_mesh((4,), ("x",))
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
 w = jax.ShapeDtypeStruct((64, 64), jnp.float32,
                          sharding=NamedSharding(mesh, P()))
 x = jax.ShapeDtypeStruct((8, 64), jnp.float32,
@@ -47,8 +41,6 @@ def f(x, w):
     return y.sum()
 c = jax.jit(f).lower(x, w).compile()
 ca = c.cost_analysis()
-if isinstance(ca, list):
-    ca = ca[0]
 import sys
 print("XLA_FLOPS", ca["flops"])
 sys.stdout.write(c.as_text())

@@ -18,10 +18,10 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.vocab_parallel import vp_cross_entropy, vp_embed
 from repro.models.model import cross_entropy
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_host_mesh
 from repro.runtime.pspec import axis_rules
 
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 rules = {"batch": ("data",), "embed": None, "ffn": "model", "vocab": "model",
          "experts": "model", "heads": None, "kv_heads": None, "seq": None,
          "kv_seq": None, "fsdp": "data"}
