@@ -734,17 +734,17 @@ def bench_telemetry(quick: bool = False) -> None:
     tight loop, which converges even on a noisy core), multiply by the
     events a traced run actually records, and express that added work
     against the NullTracer run's min-of-reps wall time. Raw traced/base
-    walls stay in the row as informational detail. Also drops non-blocking sample artifacts next to the
-    cProfile one: artifacts/trace_sample.json (a traced FrontDoor /
-    preemptive PipelineServer run with device-walk stamp spans folded
-    in) and artifacts/metrics_sample.json/.prom.
+    walls stay in the row as informational detail. Also drops non-blocking sample artifacts:
+    artifacts/trace_sample.json (a traced FrontDoor / preemptive
+    PipelineServer run plus the devdag.* phases of one small
+    run_device_dag call) and artifacts/metrics_sample.json/.prom.
     """
     from repro.core import (DEP_ELEMENTWISE, AdmissionController, BatchPolicy,
                             FrontDoor, MetricsRegistry, PipelineDAG,
                             PipelineExecutor, Stage, StageDep, Submission,
                             TokenBucket, Tracer, analyze_critical_path,
-                            build_dag_tables, collect_cache_metrics,
-                            device_walk_spans, validate_chrome_trace)
+                            collect_cache_metrics, collect_compile_metrics,
+                            validate_chrome_trace)
 
     n, width = (24_000, 96) if quick else (96_000, 96)
     basis = np.ones(width)
@@ -809,9 +809,8 @@ def bench_telemetry(quick: bool = False) -> None:
         f"overhead_margin5={margin5:.2f}% equal={1 if equal else -1} "
         f"recon={recon}")
 
-    # -- sample artifacts (non-blocking; uploaded next to the profile) -----
-    from repro.kernels.dag_walk import dag_walk
-    from repro.vee.apps import linreg_device_lowering
+    # -- sample artifacts (non-blocking; uploaded by bench-quick) ----------
+    from repro.vee.apps import linreg_device_lowering, run_device_dag
 
     sample = Tracer()
     reg = MetricsRegistry()
@@ -840,19 +839,14 @@ def bench_telemetry(quick: bool = False) -> None:
                              deadline_s=None if j % 2 else 0.05,
                              stage_costs={"work": np.full(small, 1e-7)}))
     fd.serve()
-    # device-walker lane: stamp a small fused walk into the same stream
+    # device-DAG path: the devdag.* phases of one small walk, same stream
     low = linreg_device_lowering(128, 5, tile=32)
-    ddt = build_dag_tables(low.dag, 1, "SS", n_shards=1, n_workers=2)
-    rows_tbl = ddt.tables[0].copy()
-    rows_tbl[:, 1:] *= low.tile
-    _, stamps = dag_walk(low.stages, low.operands, low.values, rows_tbl,
-                         low.tile, stamp=True)
-    device_walk_spans(stamps, [s.name for s in low.stages], sample,
-                      lane=cfg.n_workers, job="device_job")
+    run_device_dag(low, "SS", n_workers=2, tracer=sample, job="device_job")
     obj = sample.to_chrome_trace()
     assert validate_chrome_trace(obj) == [], "sample trace must be valid"
     (ART / "trace_sample.json").write_text(json.dumps(obj, indent=1) + "\n")
     collect_cache_metrics(reg)
+    collect_compile_metrics(reg)
     (ART / "metrics_sample.json").write_text(reg.to_json() + "\n")
     (ART / "metrics_sample.prom").write_text(reg.to_prometheus())
 

@@ -85,7 +85,6 @@ from .device_schedule import (
     cost_balanced_assignment,
     dag_signature,
     dag_table_cache_stats,
-    device_walk_spans,
     per_shard_tables,
     rebalance,
     rebalance_dag,
@@ -182,8 +181,10 @@ from .telemetry import (
     as_tracer,
     collect_bandit_metrics,
     collect_cache_metrics,
+    collect_compile_metrics,
     collect_queue_metrics,
     collect_server_metrics,
+    compile_counts,
     validate_chrome_trace,
 )
 from .task import RangeTask, tasks_from_schedule
@@ -212,7 +213,7 @@ __all__ = [
     "cost_balanced_assignment",
     "DeviceDagTables", "build_dag_tables", "rebalance_dag",
     "dag_signature", "build_dag_tables_cached", "dag_table_cache_stats",
-    "clear_dag_table_cache", "device_walk_spans",
+    "clear_dag_table_cache",
     "select_offline", "OnlineTuner", "default_search_space",
     "select_offline_dag", "DagTuner", "select_offline_server",
     "select_offline_device_dag",
@@ -239,4 +240,5 @@ __all__ = [
     "MetricsRegistry", "CriticalPathReport", "analyze_critical_path",
     "validate_chrome_trace", "collect_queue_metrics", "collect_cache_metrics",
     "collect_bandit_metrics", "collect_server_metrics",
+    "collect_compile_metrics", "compile_counts",
 ]

@@ -7,7 +7,7 @@ ONE correlated stream keyed by the shared ``(job, stage, chunk)``
 identity, so a makespan can finally be *explained* instead of just
 measured.
 
-Three pieces:
+Four pieces:
 
 ``Tracer``
     The span log. Recording follows the §16 amortized-event discipline:
@@ -32,6 +32,16 @@ Three pieces:
     the per-chunk path. Snapshots dump as JSON or Prometheus text
     exposition via ``launch/serve.py --metrics-out``.
 
+``phase`` and the compile counters
+    The device-DAG path (``vee.apps.run_device_dag`` down to the walker's
+    launch) names its host phases ``devdag.*`` (``DEVDAG_PHASES``). Each
+    phase opens a ``jax.profiler.TraceAnnotation``, so a profiler trace
+    holds it on the host plane on the same clock as the device's
+    operations; with an enabled tracer it also records one slice. The
+    compile counters fold JAX's own ``jax.monitoring`` events (trace,
+    lower, compile requests, persistent-cache loads) from the first
+    traced device-DAG call on; ``collect_compile_metrics`` drains them.
+
 ``analyze_critical_path``
     Walks the recorded span timeline backward from the last-finishing
     work span, telescoping the makespan into per-stage exec /
@@ -44,18 +54,25 @@ Three pieces:
 from __future__ import annotations
 
 import json
+from contextlib import contextmanager
 from dataclasses import dataclass, field
+from time import perf_counter
 
 __all__ = [
     "Span", "Tracer", "NullTracer", "NULL_TRACER",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "CriticalPathReport", "analyze_critical_path",
-    "validate_chrome_trace",
+    "validate_chrome_trace", "DEVDAG_PHASES", "phase",
+    "watch_compiles", "compile_counts", "collect_compile_metrics",
 ]
 
 # span kinds carrying real duration (the critical-path walk's alphabet);
-# everything else is an instant marker (t0 == t1)
+# everything else but the phases below is an instant marker (t0 == t1)
 WORK_KINDS = ("exec", "transfer")
+# host phases of the device-DAG path (``phase``)
+DEVDAG_PHASES = ("devdag.job", "devdag.merge", "devdag.tables",
+                 "devdag.table_put", "devdag.launch", "devdag.readback",
+                 "devdag.combine")
 # flag bits on exec spans
 F_STOLEN = 1
 F_DEVICE = 2
@@ -65,8 +82,9 @@ F_DEVICE = 2
 class Span:
     """One materialized telemetry span.
 
-    ``kind`` is the layer ("exec", "transfer", "stage", "job", or an
-    instant marker like "admission"/"preempt"/"resize"); identity is the
+    ``kind`` is the layer ("exec", "transfer", "stage", "job", a
+    device-DAG phase like "devdag.launch", or an instant marker like
+    "admission"/"preempt"/"resize"); identity is the
     shared ``(job, stage, chunk)`` triple; ``lane`` is the worker /
     device lane that ran it (-1 for scheduler-side events); ``flag`` is
     a bitmask (``F_STOLEN``, ``F_DEVICE``); ``wait_s`` is the queue wait
@@ -180,7 +198,9 @@ class Tracer:
         spans (cat "exec", "steal", or "device_walk"), the queue-wait
         slice preceding each exec (cat "queue"), and transfers. pid 2
         "jobs": one thread per job with the synthesized job/stage spans
-        and every instant marker (admission, preempt, resize, ...).
+        and every instant marker (admission, preempt, resize, ...). pid 3
+        "host", present when the device-DAG path was traced: one track of
+        its phases (cat "devdag"), nested as they ran.
         """
         ev: list[dict] = []
         us = 1e6
@@ -222,6 +242,10 @@ class Tracer:
                 ev.append({"name": s.stage or s.job, "cat": s.kind,
                            "ph": "X", "ts": s.t0 * us, "dur": s.dur * us,
                            "pid": 2, "tid": jtid(s.job), "args": args})
+            elif s.kind in DEVDAG_PHASES:
+                ev.append({"name": s.kind, "cat": "devdag", "ph": "X",
+                           "ts": s.t0 * us, "dur": s.dur * us, "pid": 3,
+                           "tid": 1, "args": args})
             else:  # instant markers
                 ev.append({"name": s.kind, "cat": s.kind, "ph": "i",
                            "ts": s.t0 * us, "s": "t", "pid": 2,
@@ -229,6 +253,11 @@ class Tracer:
         for ln in sorted(lanes):
             ev.append({"ph": "M", "pid": 1, "tid": ln, "name": "thread_name",
                        "args": {"name": f"lane {ln}"}})
+        if any(e.get("cat") == "devdag" for e in ev):
+            ev.append({"ph": "M", "pid": 3, "tid": 0, "name": "process_name",
+                       "args": {"name": "host"}})
+            ev.append({"ph": "M", "pid": 3, "tid": 1, "name": "thread_name",
+                       "args": {"name": "device-DAG path"}})
         return {"traceEvents": ev, "displayTimeUnit": "ms"}
 
     def write_chrome_trace(self, path) -> None:
@@ -266,6 +295,96 @@ NULL_TRACER = NullTracer()
 def as_tracer(tracer: Tracer | None) -> Tracer:
     """``tracer`` or the shared NullTracer — what engine ctors call."""
     return tracer if tracer is not None else NULL_TRACER
+
+
+@contextmanager
+def phase(tracer: Tracer, kind: str, job: str = "", detail: str = ""):
+    """One named host phase of the device-DAG path (``DEVDAG_PHASES``).
+
+    Always opens a ``jax.profiler.TraceAnnotation(kind)``, which a
+    running profiler records on its host plane, on the clock of the
+    device's operations, and which costs next to nothing otherwise. An
+    enabled ``tracer`` also gets one ``record_raw`` slice: ``kind``, the
+    caller's ``job`` identity, ``perf_counter`` start and end, and
+    ``detail`` (e.g. ``shard=0``). A disabled tracer reads no clock.
+    """
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(kind):
+        if not tracer.enabled:
+            yield
+            return
+        t0 = perf_counter()
+        try:
+            yield
+        finally:
+            tracer.record_raw(kind, job or tracer.job, "", -1, -1, t0,
+                              perf_counter(), detail=detail)
+
+
+# --------------------------------------------------------------------------
+# Compile-path counters: JAX's own jax.monitoring events, folded in-process
+# --------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# one per program that missed JAX's in-memory caches, around
+# compile_or_get_cached: a backend compile or a persistent-cache load
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_COMPILES = {"trace_s": 0.0, "lower_s": 0.0, "requests": 0,
+             "request_s": 0.0, "cache_loads": 0, "cache_load_s": 0.0}
+_DURATION_FIELDS = {TRACE_EVENT: "trace_s", LOWER_EVENT: "lower_s",
+                    COMPILE_EVENT: "request_s", CACHE_LOAD_EVENT: "cache_load_s"}
+_watching = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    key = _DURATION_FIELDS.get(event)
+    if key is not None:
+        _COMPILES[key] += duration
+        if event == COMPILE_EVENT:
+            _COMPILES["requests"] += 1
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == CACHE_HIT_EVENT:
+        _COMPILES["cache_loads"] += 1
+
+
+def watch_compiles() -> None:
+    """Register the compile-path listeners, once per process.
+
+    ``run_device_dag`` calls it when an enabled tracer reaches it; a
+    process that never traces that path registers nothing.
+    """
+    global _watching
+    if _watching:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+    _watching = True
+
+
+def compile_counts() -> dict:
+    """Snapshot of the compile counters since ``watch_compiles``.
+
+    ``requests``: programs that missed JAX's in-memory caches;
+    ``cache_loads`` of them came from the persistent cache, and
+    ``backend_compiles`` (the rest) ran the backend's compiler.
+    ``trace_s``/``lower_s``: seconds tracing jaxprs and lowering them to
+    MLIR; ``request_s``: seconds inside those requests, of which
+    ``cache_load_s`` reading the persistent cache. ``watching`` is False
+    (and every count 0) in a process that registered nothing.
+    """
+    c = dict(_COMPILES)
+    c["backend_compiles"] = c["requests"] - c["cache_loads"]
+    c["watching"] = _watching
+    return c
 
 
 # --------------------------------------------------------------------------
@@ -440,6 +559,19 @@ def collect_cache_metrics(reg: MetricsRegistry) -> None:
         reg.counter(f"sched_{name}_hits").inc(h)
         reg.counter(f"sched_{name}_misses").inc(m)
         reg.gauge(f"sched_{name}_hit_rate").set(h / max(1, h + m))
+
+
+def collect_compile_metrics(reg: MetricsRegistry) -> None:
+    """Compile-path counters (``compile_counts``); nothing when unwatched."""
+    c = compile_counts()
+    if not c["watching"]:
+        return
+    for key, help in (("requests", "programs past the in-memory caches"),
+                      ("cache_loads", "loaded from the persistent cache"),
+                      ("backend_compiles", "compiled by the backend")):
+        reg.counter(f"sched_compile_{key}", help).inc(c[key])
+    for key in ("trace_s", "lower_s", "request_s", "cache_load_s"):
+        reg.counter(f"sched_compile_{key[:-2]}_seconds").inc(c[key])
 
 
 def collect_bandit_metrics(reg: MetricsRegistry, scheduler) -> None:

@@ -88,5 +88,6 @@ def cc_propagate(G: jax.Array, c: jax.Array, schedule: jax.Array,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        name="cc_propagate",
     )(schedule.astype(jnp.int32), G, c.reshape(1, n), c.reshape(n, 1))
     return out.reshape(n)

@@ -48,6 +48,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.telemetry import Tracer, as_tracer, phase
 from .mode import pallas_call
 
 __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
@@ -262,8 +263,8 @@ def _out_spec(stage: WalkStage, tile: int) -> tuple[tuple[int, ...], tuple[str, 
     return tuple(stage.out_shape), ("zero",) * len(stage.out_shape)
 
 
-STAMP_ROWS = 8     # slots per stamp-buffer block (one (8, 128) int32 tile)
-STAMP_LANES = 128
+# the walker's kernel name: its operation in a profile and its HLO instruction
+KERNEL_NAME = "devdag_walker"
 
 
 def walk_call(
@@ -272,7 +273,6 @@ def walk_call(
     shapes: dict[str, tuple[int, ...]],
     n_slots: int,
     tile: int,
-    stamp: bool = False,
 ) -> Callable:
     """Build the fused walker's kernel call for a super-table of ``n_slots``.
 
@@ -281,11 +281,7 @@ def walk_call(
     Returns ``f(table, *operand_values)``, where ``table`` is the walker
     table (``_walk_table``) flattened to 1-D int32 (scalar memory pads a
     2-D table's rows to 128 lanes), giving one output per stage, in
-    ``stages`` order, plus the stamp buffer when ``stamp``: a
-    ``(ceil(n_slots / 8) * 8, 128) int32`` array whose row ``slot`` holds
-    ``(stage_id, start, size, slot)`` in its first four lanes. Eight slots
-    share one aligned ``(8, 128)`` block; each grid step rewrites only its
-    own row, so a block is complete when the walk leaves it.
+    ``stages`` order. The kernel is named ``KERNEL_NAME``.
     """
     n_inner = max(s.inner for s in stages)
     width = 3 + len(stages)
@@ -298,11 +294,6 @@ def walk_call(
         out_specs.append(pl.BlockSpec(block, _index_map(
             block, kinds, s.out_shape, width, start_col=3 + k)))
         out_shapes.append(jax.ShapeDtypeStruct(tuple(s.out_shape), s.out_dtype))
-    if stamp:
-        n_pad = -(-n_slots // STAMP_ROWS) * STAMP_ROWS
-        out_specs.append(pl.BlockSpec((STAMP_ROWS, STAMP_LANES),
-                                      lambda i, j, tbl: (i // STAMP_ROWS, 0)))
-        out_shapes.append(jax.ShapeDtypeStruct((n_pad, STAMP_LANES), jnp.int32))
 
     n_ops = len(operands)
 
@@ -321,17 +312,6 @@ def walk_call(
                 if s.combine == "sum":
                     outs[s.name][...] = jnp.zeros(s.out_shape, s.out_dtype)
 
-        if stamp:
-            # per-slot event stamp: idempotent across inner steps (each
-            # writes the same row), read back post-walk as tracer spans
-            st_ref = refs[n_ops + len(stages)]
-            shape = (STAMP_ROWS, STAMP_LANES)
-            row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-            lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            event = jnp.where(lane == 0, sid, jnp.where(
-                lane == 1, start, jnp.where(lane == 2, size, i)))
-            st_ref[...] = jnp.where(row == i % STAMP_ROWS, event, st_ref[...])
-
         for k, s in enumerate(stages):
             def run(s=s):
                 stage_ins = {n: ins[n] for n in s.operands}
@@ -346,7 +326,8 @@ def walk_call(
         in_specs=in_specs,
         out_specs=out_specs,
     )
-    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shapes)
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shapes,
+                       name=KERNEL_NAME)
 
 
 def dag_walk(
@@ -357,7 +338,9 @@ def dag_walk(
     tile: int,
     table_key: tuple | None = None,
     _dev_table: jax.Array | None = None,
-    stamp: bool = False,
+    tracer: Tracer | None = None,
+    job: str = "",
+    shard: int = 0,
 ) -> dict[str, jax.Array]:
     """Drain one shard's super-table in a single Pallas launch.
 
@@ -370,12 +353,9 @@ def dag_walk(
     ``_dev_table`` is a pre-transferred device array from
     ``dag_walk_sharded``'s double-buffered prefetch.
 
-    ``stamp=True`` adds an ``(n_slots, 4) int32`` event buffer output —
-    each slot's grid step writes ``(stage_id, start, size, slot)`` into
-    its own row (idempotent across inner steps, so the walk's own cost
-    is one small row store per slot). The buffer is read back post-walk
-    by ``core.device_schedule.device_walk_spans`` and turned into tracer
-    spans; the return becomes ``({stage: out}, stamps)``.
+    The launch (building, tracing, lowering and compiling or loading the
+    kernel, then enqueueing it) is the ``devdag.launch`` phase of
+    ``tracer`` under ``job``, tagged ``shard=<shard>`` (DESIGN.md §18).
     """
     table = np.ascontiguousarray(np.asarray(table, dtype=np.int32))
     if table.ndim != 2 or table.shape[1] != 3:
@@ -385,21 +365,20 @@ def dag_walk(
         raise ValueError("duplicate stage names")
     n_slots = len(table)
     if n_slots == 0:
-        empty = {s.name: jnp.zeros(s.out_shape, s.out_dtype) for s in stages}
-        if stamp:
-            return empty, np.zeros((0, 4), dtype=np.int32)
-        return empty
+        return {s.name: jnp.zeros(s.out_shape, s.out_dtype) for s in stages}
 
-    call = walk_call(stages, operands,
-                     {op.name: tuple(values[op.name].shape) for op in operands},
-                     n_slots, tile, stamp=stamp)
-    tbl_dev = _dev_table if _dev_table is not None \
-        else _device_table(_walk_table(table, stages), table_key)
-    out = call(tbl_dev, *[values[op.name] for op in operands])
-    named = {s.name: o for s, o in zip(stages, out)}
-    if stamp:
-        return named, np.asarray(out[len(stages)])[:n_slots, :4]
-    return named
+    tracer = as_tracer(tracer)
+    tag = f"shard={shard}"
+    tbl_dev = _dev_table
+    if tbl_dev is None:
+        with phase(tracer, "devdag.table_put", job, tag):
+            tbl_dev = _device_table(_walk_table(table, stages), table_key)
+    with phase(tracer, "devdag.launch", job, tag):
+        call = walk_call(stages, operands,
+                         {op.name: tuple(values[op.name].shape) for op in operands},
+                         n_slots, tile)
+        out = call(tbl_dev, *[values[op.name] for op in operands])
+    return {s.name: o for s, o in zip(stages, out)}
 
 
 def dag_walk_stagewise(
@@ -443,6 +422,8 @@ def dag_walk_sharded(
     tables: np.ndarray,
     tile: int,
     table_key: tuple | None = None,
+    tracer: Tracer | None = None,
+    job: str = "",
 ) -> dict[str, np.ndarray]:
     """Walk every shard's super-table and combine the per-shard outputs.
 
@@ -457,34 +438,48 @@ def dag_walk_sharded(
     (e.g. the job's dag_signature) every shard table stays
     device-resident across calls — repeat jobs of the same shape skip
     the transfer entirely.
+
+    Phases of ``tracer`` under ``job`` (DESIGN.md §18): per shard
+    ``devdag.table_put`` and ``devdag.launch``; once every shard is
+    enqueued, per shard ``devdag.readback`` (the one host copy of its
+    outputs, where the host waits for the device); then one
+    ``devdag.combine`` over the host copies.
     """
+    tracer = as_tracer(tracer)
     tables = np.ascontiguousarray(np.asarray(tables, dtype=np.int32))
     n_shards = tables.shape[0]
     key = (lambda s: (table_key, s)) if table_key is not None \
         else (lambda s: None)
+
     def put(s):
-        return _device_table(_walk_table(tables[s], stages), key(s))
+        with phase(tracer, "devdag.table_put", job, f"shard={s}"):
+            return _device_table(_walk_table(tables[s], stages), key(s))
 
     nxt = put(0) if n_shards else None
     shard_outs = []
     for s in range(n_shards):
         cur, nxt = nxt, (put(s + 1) if s + 1 < n_shards else None)
         shard_outs.append(dag_walk(stages, operands, values, tables[s], tile,
-                                   _dev_table=cur))
-    combined: dict[str, np.ndarray] = {}
-    for k, s in enumerate(stages):
-        if s.combine == "sum":
-            acc = shard_outs[0][s.name]
-            for o in shard_outs[1:]:
-                acc = acc + o[s.name]
-            combined[s.name] = np.asarray(acc)
-        else:
-            buf = np.zeros(tuple(s.out_shape),
-                           np.asarray(shard_outs[0][s.name]).dtype)
-            for sh in range(tables.shape[0]):
-                for sid, start, size in tables[sh]:
-                    if sid == k and size > 0:
-                        buf[start:start + size] = np.asarray(
-                            shard_outs[sh][s.name])[start:start + size]
-            combined[s.name] = buf
+                                   _dev_table=cur, tracer=tracer, job=job,
+                                   shard=s))
+    host = []
+    for s, outs in enumerate(shard_outs):
+        with phase(tracer, "devdag.readback", job, f"shard={s}"):
+            host.append({name: np.asarray(o) for name, o in outs.items()})
+    with phase(tracer, "devdag.combine", job):
+        combined: dict[str, np.ndarray] = {}
+        for k, s in enumerate(stages):
+            if s.combine == "sum":
+                acc = host[0][s.name]
+                for h in host[1:]:
+                    acc = acc + h[s.name]
+                combined[s.name] = acc
+            else:
+                buf = np.zeros(tuple(s.out_shape), host[0][s.name].dtype)
+                for sh in range(n_shards):
+                    out = host[sh][s.name]
+                    for sid, start, size in tables[sh]:
+                        if sid == k and size > 0:
+                            buf[start:start + size] = out[start:start + size]
+                combined[s.name] = buf
     return combined

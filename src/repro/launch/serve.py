@@ -64,14 +64,18 @@ def _telemetry(args):
 
 def _dump_telemetry(args, tracer, metrics) -> None:
     """Write the Chrome trace and the metrics snapshot (JSON + a ``.prom``
-    Prometheus-text sibling) after a traced run."""
+    Prometheus-text sibling, with the compile counters when a traced
+    device-DAG call started them) after a traced run."""
     from pathlib import Path
+
+    from ..core import collect_compile_metrics
 
     if tracer is not None:
         tracer.write_chrome_trace(args.trace_out)
         print(f"[serve] trace: {len(tracer)} events -> {args.trace_out}",
               flush=True)
     if metrics is not None:
+        collect_compile_metrics(metrics)
         out = Path(args.metrics_out)
         out.write_text(metrics.to_json() + "\n")
         prom = out.with_suffix(".prom")

@@ -459,6 +459,8 @@ def run_device_dag(
     chunk_costs: dict | None = None,
     seed: int = 0,
     stagewise: bool = False,
+    tracer=None,
+    job: str = "",
 ):
     """Execute a DeviceLowering end-to-end on the device-DAG path.
 
@@ -475,31 +477,48 @@ def run_device_dag(
     lowering memo keyed by ``dag_signature`` and the walker's
     device-resident table cache keyed by the same signature — the table
     transfer happens once, not once per job.
+
+    The call is the ``devdag.job`` phase (DESIGN.md §18) of ``tracer``
+    under ``job``; inside it ``devdag.tables`` (signature, table build,
+    scaling to rows) and the walker's own phases. A profiler trace holds
+    them whatever ``tracer`` is; an enabled tracer also records them and
+    starts the compile counters (``core.telemetry.watch_compiles``).
     """
     from ..core.device_schedule import build_dag_tables_cached, dag_signature
+    from ..core.telemetry import as_tracer, phase, watch_compiles
     from ..kernels.dag_walk import dag_walk_sharded, dag_walk_stagewise
 
-    key = dag_signature(
-        lowering.dag, 1, stage_techniques, n_shards=n_shards,
-        n_workers=n_workers, chunk_costs=chunk_costs, seed=seed)
-    ddt = build_dag_tables_cached(
-        lowering.dag, 1, stage_techniques, n_shards=n_shards,
-        n_workers=n_workers, chunk_costs=chunk_costs, seed=seed)
-    rows = ddt.tables.copy()
-    rows[:, :, 1:] *= lowering.tile  # tile units -> row space for the walker
-    if stagewise:
-        if n_shards != 1:
-            raise ValueError("stagewise baseline runs single-shard")
-        out = dag_walk_stagewise(lowering.stages, lowering.operands,
-                                 lowering.values, rows[0], lowering.tile)
-    else:
-        out = dag_walk_sharded(lowering.stages, lowering.operands,
-                               lowering.values, rows, lowering.tile,
-                               table_key=("devdag", lowering.tile, key))
-    return {k: np.asarray(v) for k, v in out.items()}, ddt
+    tracer = as_tracer(tracer)
+    if tracer.enabled:
+        watch_compiles()
+    with phase(tracer, "devdag.job", job):
+        with phase(tracer, "devdag.tables", job):
+            key = dag_signature(
+                lowering.dag, 1, stage_techniques, n_shards=n_shards,
+                n_workers=n_workers, chunk_costs=chunk_costs, seed=seed)
+            ddt = build_dag_tables_cached(
+                lowering.dag, 1, stage_techniques, n_shards=n_shards,
+                n_workers=n_workers, chunk_costs=chunk_costs, seed=seed)
+            rows = ddt.tables.copy()
+            rows[:, :, 1:] *= lowering.tile  # tile units -> row space
+        if stagewise:
+            if n_shards != 1:
+                raise ValueError("stagewise baseline runs single-shard")
+            out = dag_walk_stagewise(lowering.stages, lowering.operands,
+                                     lowering.values, rows[0], lowering.tile)
+        else:
+            # an untraced call keeps the walker's plain signature, so a
+            # wrapper of dag_walk_sharded (fault injection) sees no change
+            traced = {"tracer": tracer, "job": job} if tracer.enabled else {}
+            out = dag_walk_sharded(lowering.stages, lowering.operands,
+                                   lowering.values, rows, lowering.tile,
+                                   table_key=("devdag", lowering.tile, key),
+                                   **traced)
+        return {k: np.asarray(v) for k, v in out.items()}, ddt
 
 
-def merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
+def merge_device_lowerings(lowerings: list[DeviceLowering], tracer=None,
+                           job: str = "") -> DeviceLowering:
     """Coalesce same-tile DeviceLowerings into ONE super-table launch (§14).
 
     The front door's batching on the device path: member ``j``'s stages,
@@ -511,8 +530,16 @@ def merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
     stay disjoint (each keeps its own operands and accumulators), so the
     merged run is bit-equal to running each lowering alone. ``finalize``
     returns the list of per-member finalize results;
-    ``split_device_values`` recovers per-member stage values.
+    ``split_device_values`` recovers per-member stage values. The merge
+    is the ``devdag.merge`` phase of ``tracer`` under ``job``.
     """
+    from ..core.telemetry import as_tracer, phase
+
+    with phase(as_tracer(tracer), "devdag.merge", job):
+        return _merge_device_lowerings(lowerings)
+
+
+def _merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
     from ..core.admission import BATCH_SEP, merge_dags
 
     if not lowerings:

@@ -14,8 +14,9 @@ Plus: critical-path attribution telescoping to the measured makespan and
 reconciling against DagStats on BOTH the real pool and simulate_dag
 replays; the slot-vs-deque queue-wait differential (the wait_s
 reconciliation fix); the uniform TransferEvent/PreemptionEvent result
-surfaces; the device-walk stamp buffer -> span conversion; and the
-MetricsRegistry (memoization, Prometheus exposition, drain-time
+surfaces; the device-DAG path's ``devdag.*`` phases (tracer rows, the
+profiler's host plane, the Chrome export) and the compile-path counters;
+and the MetricsRegistry (memoization, Prometheus exposition, drain-time
 collectors over the queues' uniform ``counters()`` API).
 """
 
@@ -44,8 +45,9 @@ from repro.core import (
     Tracer,
     analyze_critical_path,
     as_tracer,
+    collect_compile_metrics,
     collect_queue_metrics,
-    device_walk_spans,
+    compile_counts,
     simulate_dag,
     validate_chrome_trace,
 )
@@ -55,7 +57,7 @@ from repro.core.queues import (
     SlotCentralizedQueue,
     SlotDistributedQueues,
 )
-from repro.core.telemetry import F_DEVICE, WORK_KINDS
+from repro.core.telemetry import DEVDAG_PHASES, F_DEVICE, WORK_KINDS
 
 TECHS = ["STATIC", "SS", "MFSC", "GSS", "FAC2", "TSS"]
 LAYOUTS = ["CENTRALIZED", "PERCORE", "PERGROUP"]
@@ -329,53 +331,242 @@ def test_preemptive_runner_marks_checkpoint():
 
 
 # ---------------------------------------------------------------------------
-# device-walk stamp buffer -> spans
+# device-DAG path phases and compile counters
 # ---------------------------------------------------------------------------
 
-def test_device_walk_spans_from_stamps():
-    stamps = np.array([[0, 0, 8, 0], [0, 8, 8, 1], [1, 0, 16, 2],
-                       [1, 0, 0, 3]], dtype=np.int32)  # last row: padding
-    tracer = Tracer(job="dev")
-    n = device_walk_spans(stamps, ["a", "b"], tracer, lane=5, job="dev",
-                          row_costs={"a": np.full(16, 2.0),
-                                     "b": np.ones(16)})
-    assert n == 3
-    execs = [s for s in tracer.spans() if s.kind == "exec"]
-    assert len(execs) == 3
-    assert all(s.device and s.lane == 5 for s in execs)
-    assert [s.stage for s in execs] == ["a", "a", "b"]
-    # virtual clock: slot durations follow the row costs, back to back
-    assert execs[0].t0 == pytest.approx(0.0)
-    assert execs[0].t1 == pytest.approx(16.0)  # 8 rows x cost 2
-    assert execs[2].t1 == pytest.approx(48.0)
-    assert device_walk_spans(stamps, ["a", "b"], NULL_TRACER) == 0
-    assert validate_chrome_trace(tracer.to_chrome_trace()) == []
+def _two_stage_lowering(n=256, tile=32, seed=3):
+    """A concat stage and a sum stage reading its rows: no full edge, so
+    the walk may take several shards."""
+    import jax.numpy as jnp
+
+    from repro.kernels.dag_walk import WalkOperand, WalkStage
+    from repro.vee.apps import DeviceLowering
+
+    def op(inputs, s, z):
+        return np.zeros(z)
+
+    dag = PipelineDAG([
+        Stage("scaled", n // tile, op, combine="concat"),
+        Stage("total", n // tile, op, combine="sum",
+              deps=(StageDep("scaled", DEP_ELEMENTWISE),)),
+    ])
+
+    def scaled_body(ctx, ins, out):
+        out[...] = ins["x"][...] * 2.0 + 1.0
+
+    def total_body(ctx, ins, out):
+        out[...] += ins["scaled"][...].sum(axis=0, keepdims=True)
+
+    stages = [
+        WalkStage("scaled", n, (n, 8), jnp.float32, "concat", scaled_body,
+                  operands=("x",)),
+        WalkStage("total", n, (1, 8), jnp.float32, "sum", total_body,
+                  reads=(("scaled", "rows"),)),
+    ]
+    x = np.random.default_rng(seed).uniform(size=(n, 8)).astype(np.float32)
+    return DeviceLowering(dag, stages, [WalkOperand("x", (tile, 8),
+                                                    ("row", "zero"))],
+                          {"x": jnp.asarray(x)}, tile)
 
 
-def test_dag_walk_stamp_buffer():
-    from repro.core import build_dag_tables
-    from repro.kernels.dag_walk import dag_walk
-    from repro.vee.apps import linreg_device_lowering
+def _phases(tracer):
+    return [s for s in tracer.spans() if s.kind in DEVDAG_PHASES]
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_run_device_dag_phases_nest_in_one_job(n_shards):
+    from repro.vee.apps import run_device_dag
+
+    tracer = Tracer()
+    run_device_dag(_two_stage_lowering(), "STATIC", n_shards=n_shards,
+                   n_workers=2, tracer=tracer, job="7")
+    spans = _phases(tracer)
+    kinds = sorted(s.kind for s in spans)
+    per_shard = ["devdag.launch", "devdag.readback", "devdag.table_put"]
+    assert kinds == sorted(["devdag.job", "devdag.tables", "devdag.combine"]
+                           + per_shard * n_shards)
+    assert {s.job for s in spans} == {"7"}
+    (job,) = [s for s in spans if s.kind == "devdag.job"]
+    for s in spans:
+        assert job.t0 <= s.t0 <= s.t1 <= job.t1
+    for kind in per_shard:
+        assert sorted(s.detail for s in spans if s.kind == kind) == \
+            [f"shard={k}" for k in range(n_shards)]
+    # each phase in the order the call runs them
+    first = {k: min(s.t0 for s in spans if s.kind == k) for k in set(kinds)}
+    assert (first["devdag.tables"] <= first["devdag.table_put"]
+            <= first["devdag.launch"] <= first["devdag.readback"]
+            <= first["devdag.combine"])
+
+
+def test_merged_run_adds_merge_phase():
+    from repro.vee.apps import (linreg_device_lowering,
+                                merge_device_lowerings, run_device_dag)
+
+    tracer = Tracer()
+    lows = [linreg_device_lowering(128, 5, tile=32, seed=s) for s in (1, 2)]
+    merged = merge_device_lowerings(lows, tracer=tracer, job="3")
+    run_device_dag(merged, "SS", n_workers=2, tracer=tracer, job="3")
+    spans = _phases(tracer)
+    (merge,) = [s for s in spans if s.kind == "devdag.merge"]
+    (job,) = [s for s in spans if s.kind == "devdag.job"]
+    assert merge.job == job.job == "3"
+    assert merge.t1 <= job.t0
+    assert sum(s.kind == "devdag.launch" for s in spans) == 1
+
+
+def test_untraced_call_records_nothing_and_registers_no_listener():
+    import jax
+    from jax._src import monitoring
+
+    from repro.core import telemetry
+    from repro.vee.apps import linreg_device_lowering, run_device_dag
+
+    if telemetry._watching:  # start from a process that watches nothing
+        jax.monitoring.unregister_event_duration_listener(
+            telemetry._on_duration)
+        jax.monitoring.unregister_event_listener(telemetry._on_event)
+        telemetry._watching = False
+
+    def listeners():
+        return (monitoring.get_event_duration_listeners(),
+                monitoring.get_event_listeners())
 
     low = linreg_device_lowering(128, 5, tile=32)
-    ddt = build_dag_tables(low.dag, 1, "SS", n_shards=1, n_workers=2)
-    rows = ddt.tables[0].copy()
-    rows[:, 1:] *= low.tile
-    plain = dag_walk(low.stages, low.operands, low.values, rows, low.tile)
-    out, stamps = dag_walk(low.stages, low.operands, low.values, rows,
-                           low.tile, stamp=True)
-    # stamping is read-only: outputs bit-equal to the unstamped walk
+    before = listeners()
+    plain, _ = run_device_dag(low, "SS", n_workers=2)
+    assert listeners() == before and not telemetry._watching
+    assert len(NULL_TRACER) == 0
+    tracer = Tracer()
+    traced, _ = run_device_dag(low, "SS", n_workers=2, tracer=tracer, job="1")
+    assert telemetry._watching
+    durations, events = listeners()
+    assert durations.count(telemetry._on_duration) == 1
+    assert events.count(telemetry._on_event) == 1
+    assert set(plain) == set(traced)
     for k in plain:
-        assert np.array_equal(np.asarray(plain[k]), np.asarray(out[k]))
-    stamps = np.asarray(stamps)
-    assert stamps.shape == (len(rows), 4)
-    live = stamps[stamps[:, 2] > 0]
-    assert np.array_equal(live[:, :3], rows[rows[:, 2] > 0])
-    # slot ids are the walk order
-    assert np.array_equal(live[:, 3], np.flatnonzero(rows[:, 2] > 0))
-    tracer = Tracer(job="walk")
-    n = device_walk_spans(live, [s.name for s in low.stages], tracer, lane=9)
-    assert n == len(live)
+        assert np.array_equal(plain[k], traced[k])
+
+
+def test_disabled_phase_reads_no_clock(monkeypatch):
+    from repro.core import telemetry
+
+    def clock():
+        raise AssertionError("a disabled phase read the clock")
+
+    monkeypatch.setattr(telemetry, "perf_counter", clock)
+    with telemetry.phase(NULL_TRACER, "devdag.launch", "1"):
+        pass
+    with pytest.raises(AssertionError):
+        with telemetry.phase(Tracer(), "devdag.launch", "1"):
+            pass
+
+
+def test_compile_counter_splits_synthetic_events():
+    import jax
+
+    from repro.core import telemetry
+
+    telemetry.watch_compiles()
+    before = compile_counts()
+    rec = jax.monitoring.record_event_duration_secs
+    rec(telemetry.TRACE_EVENT, 0.25)
+    rec(telemetry.LOWER_EVENT, 0.5)
+    for _ in range(2):  # two persistent-cache loads
+        jax.monitoring.record_event(telemetry.CACHE_HIT_EVENT)
+        rec(telemetry.CACHE_LOAD_EVENT, 0.125)
+        rec(telemetry.COMPILE_EVENT, 0.25)
+    rec(telemetry.COMPILE_EVENT, 2.0)  # one backend compile
+    rec("/jax/some/other_event", 9.0)
+    after = compile_counts()
+    delta = {k: after[k] - before[k] for k in before if k != "watching"}
+    assert delta == {"trace_s": 0.25, "lower_s": 0.5, "requests": 3,
+                     "request_s": 2.5, "cache_loads": 2,
+                     "cache_load_s": 0.25, "backend_compiles": 1}
+    reg = MetricsRegistry()
+    collect_compile_metrics(reg)
+    counters = reg.snapshot()["counters"]
+    assert counters["sched_compile_requests"] == after["requests"]
+    assert counters["sched_compile_backend_compiles"] == \
+        after["requests"] - after["cache_loads"]
+    assert counters["sched_compile_cache_load_seconds"] == \
+        after["cache_load_s"]
+
+
+def test_phase_spans_export_as_slices():
+    from repro.core import telemetry
+
+    tracer = Tracer()
+    with telemetry.phase(tracer, "devdag.job", "9"):
+        with telemetry.phase(tracer, "devdag.launch", "9", "shard=0"):
+            pass
+    tracer.mark("admit", 0.0, job="9")
+    obj = tracer.to_chrome_trace()
+    assert validate_chrome_trace(obj) == []
+    slices = [e for e in obj["traceEvents"] if e.get("cat") == "devdag"]
+    assert sorted(e["name"] for e in slices) == ["devdag.job",
+                                                 "devdag.launch"]
+    assert all(e["ph"] == "X" and e["pid"] == 3 and e["tid"] == 1
+               for e in slices)
+    (hull,) = [s for s in tracer.spans() if s.kind == "job"]
+    (job,) = [s for s in tracer.spans() if s.kind == "devdag.job"]
+    assert hull.t0 <= job.t0 and job.t1 <= hull.t1
+
+
+def test_profiler_trace_holds_devdag_spans(tmp_path):
+    import jax
+
+    from repro.vee.apps import linreg_device_lowering, run_device_dag
+
+    low = linreg_device_lowering(128, 5, tile=32)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_device_dag(low, "SS", n_workers=2)
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(xplane))
+    host = [e for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("devdag.")]
+    want = set(DEVDAG_PHASES) - {"devdag.merge"}
+    assert {e.name for e in host} == want
+    (job,) = [e for e in host if e.name == "devdag.job"]
+    assert all(job.start_ns <= e.start_ns and e.end_ns <= job.end_ns
+               for e in host)
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_sharded_combine_from_host_copies(n_shards):
+    """Reading each shard back once, then combining on the host, gives
+    the values the per-slot combine of the device arrays gave."""
+    from repro.core import build_dag_tables
+    from repro.kernels.dag_walk import dag_walk, dag_walk_sharded
+
+    low = _two_stage_lowering()
+    ddt = build_dag_tables(low.dag, 1, "STATIC", n_shards=n_shards,
+                           n_workers=2)
+    rows = ddt.tables.copy()
+    rows[:, :, 1:] *= low.tile
+    got = dag_walk_sharded(low.stages, low.operands, low.values, rows,
+                           low.tile)
+    shard_outs = [dag_walk(low.stages, low.operands, low.values, rows[s],
+                           low.tile) for s in range(n_shards)]
+    acc = shard_outs[0]["total"]
+    for o in shard_outs[1:]:
+        acc = acc + o["total"]
+    assert np.array_equal(got["total"], np.asarray(acc))
+    want = np.zeros((low.stages[0].n_rows, 8), np.float32)
+    for sh in range(n_shards):
+        for sid, start, size in rows[sh]:
+            if sid == 0 and size > 0:
+                want[start:start + size] = np.asarray(
+                    shard_outs[sh]["scaled"])[start:start + size]
+    assert np.array_equal(got["scaled"], want)
+    x = np.asarray(low.values["x"])
+    np.testing.assert_allclose(got["scaled"], x * 2 + 1, rtol=1e-6)
+    np.testing.assert_allclose(got["total"][0], (x * 2 + 1).sum(axis=0),
+                               rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
