@@ -336,15 +336,18 @@ def bench_device_cache(quick: bool = False) -> None:
     ``device_dag_relower_cache`` is the CI-gated row: a stream of jobs
     sharing one DAG shape (the front door's recurring batch_signature
     case — operand values differ, schedule doesn't) must hit both the
-    host lowering memo (``build_dag_tables_cached``) and the walker's
-    device-resident table cache on every job after the first
-    (hit_margin >= 0 asserts a >= 50% hit rate; the 6-job stream yields
-    exactly 5/6), and the cached run must stay bit-equal to a cold run
-    (equal=1).
+    host lowering memo (``build_dag_tables_cached``), the walker's
+    device-resident table cache and its compiled call memo
+    (``walker_call_cache_stats``) on every job after the first
+    (hit_margin >= 0 asserts a >= 50% hit rate on each; the 6-job stream
+    yields exactly 5/6), and the cached run must stay bit-equal to a cold
+    run (equal=1).
     """
     from repro.core import clear_dag_table_cache, dag_table_cache_stats
     from repro.kernels.dag_walk import (clear_device_table_cache,
-                                        device_table_cache_stats)
+                                        clear_walker_call_cache,
+                                        device_table_cache_stats,
+                                        walker_call_cache_stats)
     from repro.vee.apps import linreg_device_lowering, run_device_dag
 
     n_jobs = 6
@@ -352,6 +355,7 @@ def bench_device_cache(quick: bool = False) -> None:
             for s in range(1, n_jobs + 1)]  # same shape, different values
     clear_dag_table_cache()
     clear_device_table_cache()
+    clear_walker_call_cache()
     t0 = time.perf_counter()
     run_device_dag(lows[0], "GSS")
     cold = time.perf_counter() - t0
@@ -361,13 +365,14 @@ def bench_device_cache(quick: bool = False) -> None:
     warm = (time.perf_counter() - t0) / (n_jobs - 1)
     lstats = dag_table_cache_stats()
     tstats = device_table_cache_stats()
-    hit_rate = min(
-        lstats["hits"] / max(1, lstats["hits"] + lstats["misses"]),
-        tstats["hits"] / max(1, tstats["hits"] + tstats["misses"])) * 100
+    wstats = walker_call_cache_stats()
+    hit_rate = min(st["hits"] / max(1, st["hits"] + st["misses"])
+                   for st in (lstats, tstats, wstats)) * 100
 
     warm_vals, _ = run_device_dag(lows[0], "GSS")   # fully cached
     clear_dag_table_cache()
     clear_device_table_cache()
+    clear_walker_call_cache()
     cold_vals, _ = run_device_dag(lows[0], "GSS")   # cold relower
     equal = int(all(np.array_equal(warm_vals[k], cold_vals[k])
                     for k in cold_vals))
@@ -375,6 +380,7 @@ def bench_device_cache(quick: bool = False) -> None:
         f"cold={cold * 1e6:.1f}us warm={warm * 1e6:.1f}us "
         f"lower_hits={lstats['hits']} lower_misses={lstats['misses']} "
         f"table_hits={tstats['hits']} table_misses={tstats['misses']} "
+        f"walker_hits={wstats['hits']} walker_misses={wstats['misses']} "
         f"jobs={n_jobs} hit_margin={hit_rate - 50.0:.2f}% equal={equal}")
 
 

@@ -546,12 +546,14 @@ def collect_queue_metrics(reg: MetricsRegistry, counters: dict,
 
 
 def collect_cache_metrics(reg: MetricsRegistry) -> None:
-    """Lowering-memo + device-resident table cache hit rates (§16)."""
+    """Lowering-memo, device-resident table and walker call hit rates (§16)."""
     from .device_schedule import dag_table_cache_stats
     pairs = [("lowering_cache", dag_table_cache_stats())]
     try:
-        from ..kernels.dag_walk import device_table_cache_stats
+        from ..kernels.dag_walk import (device_table_cache_stats,
+                                        walker_call_cache_stats)
         pairs.append(("device_table_cache", device_table_cache_stats()))
+        pairs.append(("walker_call_cache", walker_call_cache_stats()))
     except Exception:  # pragma: no cover - kernels unavailable
         pass
     for name, st in pairs:

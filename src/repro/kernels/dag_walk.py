@@ -39,6 +39,7 @@ identical per-stage order, so their results match bit-wise.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -53,7 +54,8 @@ from .mode import pallas_call
 
 __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
            "dag_walk_stagewise", "dag_walk_sharded",
-           "device_table_cache_stats", "clear_device_table_cache"]
+           "device_table_cache_stats", "clear_device_table_cache",
+           "walker_call_cache_stats", "clear_walker_call_cache"]
 
 
 # ---------------------------------------------------------------------------
@@ -81,6 +83,52 @@ def clear_device_table_cache() -> None:
     _DEVICE_TABLE_CACHE.clear()
     _DEVICE_TABLE_STATS["hits"] = 0
     _DEVICE_TABLE_STATS["misses"] = 0
+
+
+# ---------------------------------------------------------------------------
+# compiled walker call memo (DESIGN.md §16)
+#
+# ``walk_call`` builds a new kernel closure and ``pallas_call`` each time,
+# so an eager call traces and lowers the walker anew on every launch: JAX's
+# in-memory caches key on the fresh jaxpr and miss. ``dag_walk`` instead
+# keeps one ``jax.jit`` of the call per structure (stages, operands, their
+# shapes and dtypes, slot count, tile); a repeat launch of that structure
+# is dispatched from jit's own caches, with no tracing, lowering or cache
+# load. The table stays a runtime argument (scalar prefetch), so another
+# schedule with the same slot count reuses the compiled walker. Stage
+# bodies are keyed as they compare, so the lowerings build them from
+# module-level functions or frozen dataclasses of their constants: two
+# jobs of one shape with different data then share one entry. It memoizes
+# a compiled program, never a result: the kernel runs on every launch.
+# ---------------------------------------------------------------------------
+
+
+def walker_call_cache_stats() -> dict:
+    """Compiled walker call memo counters: ``{"hits", "misses", "size"}``."""
+    info = _jitted_walker.cache_info()
+    return {"hits": info.hits, "misses": info.misses, "size": info.currsize}
+
+
+def clear_walker_call_cache() -> None:
+    """Drop the memoized walker calls and reset the hit/miss counters."""
+    _jitted_walker.cache_clear()
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_walker(stages: tuple[WalkStage, ...],
+                   operands: tuple[WalkOperand, ...],
+                   shapes: tuple[tuple[tuple[int, ...], Any], ...],
+                   n_slots: int, tile: int) -> Callable:
+    """One ``jax.jit`` of ``walk_call`` per structure.
+
+    Stages and operands are frozen dataclasses, so the key compares them
+    by value; a stage body compares as the body itself does (by identity
+    for a plain function, by its fields for a frozen-dataclass body).
+    ``shapes`` holds each operand's ``(shape, dtype)`` in operand order.
+    """
+    return jax.jit(walk_call(list(stages), list(operands),
+                             {op.name: shape for op, (shape, _) in zip(operands, shapes)},
+                             n_slots, tile))
 
 
 def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
@@ -353,9 +401,14 @@ def dag_walk(
     ``_dev_table`` is a pre-transferred device array from
     ``dag_walk_sharded``'s double-buffered prefetch.
 
-    The launch (building, tracing, lowering and compiling or loading the
-    kernel, then enqueueing it) is the ``devdag.launch`` phase of
-    ``tracer`` under ``job``, tagged ``shard=<shard>`` (DESIGN.md §18).
+    The kernel call is jitted once per structure (stages, operands, their
+    shapes and dtypes, ``n_slots``, ``tile``) and kept in a bounded memo
+    (``walker_call_cache_stats``); the table is a runtime argument, so a
+    repeat launch of the same structure, whatever its schedule, neither
+    traces nor lowers. The launch (the memo lookup and the dispatch, plus
+    on a miss the trace, lowering and compile or cache load) is the
+    ``devdag.launch`` phase of ``tracer`` under ``job``, tagged
+    ``shard=<shard>`` (DESIGN.md §18).
     """
     table = np.ascontiguousarray(np.asarray(table, dtype=np.int32))
     if table.ndim != 2 or table.shape[1] != 3:
@@ -374,9 +427,11 @@ def dag_walk(
         with phase(tracer, "devdag.table_put", job, tag):
             tbl_dev = _device_table(_walk_table(table, stages), table_key)
     with phase(tracer, "devdag.launch", job, tag):
-        call = walk_call(stages, operands,
-                         {op.name: tuple(values[op.name].shape) for op in operands},
-                         n_slots, tile)
+        call = _jitted_walker(
+            tuple(stages), tuple(operands),
+            tuple((tuple(values[op.name].shape), values[op.name].dtype)
+                  for op in operands),
+            n_slots, tile)
         out = call(tbl_dev, *[values[op.name] for op in operands])
     return {s.name: o for s, o in zip(stages, out)}
 

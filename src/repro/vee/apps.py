@@ -539,6 +539,24 @@ def merge_device_lowerings(lowerings: list[DeviceLowering], tracer=None,
         return _merge_device_lowerings(lowerings)
 
 
+@dataclass(frozen=True)
+class _MemberBody:
+    """A batch member's stage body, called with its own (unsuffixed) names.
+
+    Compares equal when it wraps an equal body, so two merges of members
+    of the same shapes give equal ``WalkStage``s and share one compiled
+    walker (``kernels.dag_walk.walker_call_cache_stats``).
+    """
+
+    body: object
+
+    def __call__(self, ctx, ins, out):
+        from ..core.admission import BATCH_SEP
+
+        self.body(ctx, {k.rsplit(BATCH_SEP, 1)[0]: v for k, v in ins.items()},
+                  out)
+
+
 def _merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
     from ..core.admission import BATCH_SEP, merge_dags
 
@@ -548,18 +566,12 @@ def _merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
     if len(tiles) != 1:
         raise ValueError(f"cannot merge lowerings with mixed tiles {tiles}")
 
-    def _wrap_body(body):
-        def wrapped(ctx, ins, out):
-            body(ctx, {k.rsplit(BATCH_SEP, 1)[0]: v for k, v in ins.items()},
-                 out)
-        return wrapped
-
     by_name, operands, values = {}, [], {}
     for j, low in enumerate(lowerings):
         for st in low.stages:
             renamed = dataclasses.replace(
                 st, name=f"{st.name}{BATCH_SEP}{j}",
-                body=_wrap_body(st.body),
+                body=_MemberBody(st.body),
                 operands=tuple(f"{o}{BATCH_SEP}{j}" for o in st.operands),
                 reads=tuple((f"{p}{BATCH_SEP}{j}", kind)
                             for p, kind in st.reads))
@@ -597,6 +609,87 @@ def split_device_values(values: dict, n_members: int) -> list[dict]:
     return out
 
 
+# ---------------------------------------------------------------------------
+# per-tile math of the device lowerings, shared by host ops and walker
+# bodies. The bodies are module-level functions, or frozen dataclasses of
+# the constants they need, so two lowerings of one shape (new data, same
+# structure) give equal WalkStages and share one compiled walker
+# (kernels.dag_walk.walker_call_cache_stats)
+# ---------------------------------------------------------------------------
+
+
+def _moments_tile(Wb):
+    return Wb.sum(axis=1, keepdims=True), (Wb * Wb).sum(axis=1, keepdims=True)
+
+
+def _syrk_tile(Wb, M, n: int, d: int):
+    """Standardize a ``[X, 1, y]^T`` tile of ``n`` rows against the full
+    moments ``M`` and return the first ``d + 1`` rows of its Gram matrix."""
+    import jax
+    import jax.numpy as jnp
+
+    mean = M[:, 0:1] / n
+    std = jnp.sqrt(jnp.maximum(M[:, 1:2] / n - mean * mean, 0.0))
+    is_x = jax.lax.broadcasted_iota(jnp.int32, mean.shape, 0) < d
+    mean = jnp.where(is_x, mean, 0.0)
+    std = jnp.where(is_x & (std != 0), std, 1.0)
+    Z = (Wb - mean) / std
+    gram = jax.lax.dot_general(  # Z Z^T on the matrix unit, full f32
+        Z, Z, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return gram[:d + 1]
+
+
+def _linreg_moments_body(ctx, ins, out):
+    sums, squares = _moments_tile(ins["W"][...])
+    out[:, 0:1] += sums
+    out[:, 1:2] += squares
+
+
+@dataclass(frozen=True)
+class _LinregSyrkBody:
+    """``syrk_gemv``'s walker body for ``n`` rows of ``d`` features."""
+
+    n: int
+    d: int
+
+    def __call__(self, ctx, ins, out):
+        out[...] += _syrk_tile(ins["W"][...], ins["moments"][...], self.n,
+                               self.d)
+
+
+def _norms_tile(Rb):
+    return (Rb * Rb).sum(axis=0, keepdims=True)
+
+
+def _bias_tile(Rb):
+    return Rb.mean(axis=1, keepdims=True)
+
+
+def _scores_tile(Rb, norms, bias):
+    import jax
+    import jax.numpy as jnp
+
+    S = Rb / (jnp.sqrt(norms) + 1e-9) - bias
+    item = jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)
+    best = jnp.where(S == S.max(axis=1, keepdims=True), item, S.shape[1])
+    return best.min(axis=1, keepdims=True)
+
+
+def _item_norms_body(ctx, ins, out):
+    out[...] += _norms_tile(ins["R"][...])
+
+
+def _user_bias_body(ctx, ins, out):
+    out[...] = _bias_tile(ins["R"][...])
+
+
+def _scores_body(ctx, ins, out):
+    out[...] = _scores_tile(ins["R"][...], ins["item_norms"][...],
+                            ins["user_bias"][...])
+
+
 def linreg_device_lowering(
     num_rows: int,
     num_cols: int,
@@ -618,7 +711,6 @@ def linreg_device_lowering(
     ``[X1^T X1 | X1^T y]``, as one float32 matrix product.
     Host ops and device bodies share the per-tile math.
     """
-    import jax
     import jax.numpy as jnp
 
     from ..kernels.dag_walk import WalkOperand, WalkStage
@@ -636,22 +728,6 @@ def linreg_device_lowering(
     W[d + 1] = XY[:, d]
     del XY
 
-    def _moments_tile(Wb):
-        return Wb.sum(axis=1, keepdims=True), (Wb * Wb).sum(axis=1, keepdims=True)
-
-    def _syrk_tile(Wb, M):
-        mean = M[:, 0:1] / n
-        std = jnp.sqrt(jnp.maximum(M[:, 1:2] / n - mean * mean, 0.0))
-        is_x = jax.lax.broadcasted_iota(jnp.int32, mean.shape, 0) < d
-        mean = jnp.where(is_x, mean, 0.0)
-        std = jnp.where(is_x & (std != 0), std, 1.0)
-        Z = (Wb - mean) / std
-        gram = jax.lax.dot_general(  # Z Z^T on the matrix unit, full f32
-            Z, Z, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        return gram[:d + 1]
-
     def moments_op(inputs, s, z):
         acc = None
         for t in range(s, s + z):
@@ -664,7 +740,7 @@ def linreg_device_lowering(
         M = jnp.asarray(inputs["moments"])
         acc = None
         for t in range(s, s + z):
-            v = _syrk_tile(jnp.asarray(W[:, t * tile:(t + 1) * tile]), M)
+            v = _syrk_tile(jnp.asarray(W[:, t * tile:(t + 1) * tile]), M, n, d)
             acc = v if acc is None else acc + v
         return acc
 
@@ -674,19 +750,11 @@ def linreg_device_lowering(
               deps=(StageDep("moments", DEP_FULL),)),
     ])
 
-    def moments_body(ctx, ins, out):
-        sums, squares = _moments_tile(ins["W"][...])
-        out[:, 0:1] += sums
-        out[:, 1:2] += squares
-
-    def syrk_body(ctx, ins, out):
-        out[...] += _syrk_tile(ins["W"][...], ins["moments"][...])
-
     stages = [
-        WalkStage("moments", n, (d + 2, 2), jnp.float32, "sum", moments_body,
-                  operands=("W",)),
+        WalkStage("moments", n, (d + 2, 2), jnp.float32, "sum",
+                  _linreg_moments_body, operands=("W",)),
         WalkStage("syrk_gemv", n, (d + 1, d + 2), jnp.float32, "sum",
-                  syrk_body, operands=("W",),
+                  _LinregSyrkBody(n, d), operands=("W",),
                   reads=(("moments", "full"),)),
     ]
     operands = [WalkOperand("W", (d + 2, tile), ("zero", "row"))]
@@ -738,7 +806,6 @@ def recommendation_device_lowering(
     row reductions stay on the axis they reduce to; ``scores`` picks the
     first item of maximal score, as ``np.argmax`` does.
     """
-    import jax
     import jax.numpy as jnp
 
     from ..kernels.dag_walk import WalkOperand, WalkStage
@@ -747,18 +814,6 @@ def recommendation_device_lowering(
         raise ValueError(f"n_users={n_users} must be a multiple of tile={tile}")
     R = _ratings(n_users, n_items, density, seed).astype(np.float32)
     units = n_users // tile
-
-    def _norms_tile(Rb):
-        return (Rb * Rb).sum(axis=0, keepdims=True)
-
-    def _bias_tile(Rb):
-        return Rb.mean(axis=1, keepdims=True)
-
-    def _scores_tile(Rb, norms, bias):
-        S = Rb / (jnp.sqrt(norms) + 1e-9) - bias
-        item = jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)
-        best = jnp.where(S == S.max(axis=1, keepdims=True), item, S.shape[1])
-        return best.min(axis=1, keepdims=True)
 
     def item_norms_op(inputs, s, z):
         acc = None
@@ -787,23 +842,13 @@ def recommendation_device_lowering(
                     StageDep("user_bias", DEP_ELEMENTWISE))),
     ])
 
-    def item_norms_body(ctx, ins, out):
-        out[...] += _norms_tile(ins["R"][...])
-
-    def user_bias_body(ctx, ins, out):
-        out[...] = _bias_tile(ins["R"][...])
-
-    def scores_body(ctx, ins, out):
-        out[...] = _scores_tile(ins["R"][...], ins["item_norms"][...],
-                                ins["user_bias"][...])
-
     stages = [
         WalkStage("item_norms", n_users, (1, n_items), jnp.float32, "sum",
-                  item_norms_body, operands=("R",)),
+                  _item_norms_body, operands=("R",)),
         WalkStage("user_bias", n_users, (n_users, 1), jnp.float32, "concat",
-                  user_bias_body, operands=("R",)),
+                  _user_bias_body, operands=("R",)),
         WalkStage("scores", n_users, (n_users, 1), jnp.int32, "concat",
-                  scores_body, operands=("R",),
+                  _scores_body, operands=("R",),
                   reads=(("item_norms", "full"), ("user_bias", "rows"))),
     ]
     operands = [WalkOperand("R", (tile, n_items), ("row", "zero"))]

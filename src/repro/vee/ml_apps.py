@@ -298,6 +298,13 @@ def _rows_op(fn):
     return op
 
 
+def _experts_body(ctx, ins, out):
+    """The ``experts`` walker body: one expert's slab per slot. Module-level,
+    so lowerings of one shape share one compiled walker."""
+    out[...] = _expert_tile(ins["xdisp"][...], ins["wi"][...][0],
+                            ins["wo"][...][0])
+
+
 def moe_device_lowering(low: Lowered) -> DeviceLowering:
     """The MoE ``experts`` fan-out lowered for the fused device walker.
 
@@ -338,12 +345,8 @@ def moe_device_lowering(low: Lowered) -> DeviceLowering:
 
     dag = PipelineDAG([Stage("experts", e, experts_tile_op, combine="concat")])
 
-    def experts_body(ctx, ins, out):
-        out[...] = _expert_tile(ins["xdisp"][...], ins["wi"][...][0],
-                                ins["wo"][...][0])
-
     stages = [WalkStage("experts", e * cap, (e * cap, d), jnp.float32,
-                        "concat", experts_body,
+                        "concat", _experts_body,
                         operands=("xdisp", "wi", "wo"))]
     operands = [
         WalkOperand("xdisp", (cap, d), ("row", "zero")),

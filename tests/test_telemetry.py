@@ -569,6 +569,30 @@ def test_sharded_combine_from_host_copies(n_shards):
                                rtol=1e-5)
 
 
+def test_cache_metrics_export_walker_call_cache():
+    """The walker call memo's counters reach the registry beside the
+    device table cache's: one miss, then a hit per repeat launch."""
+    from repro.core.telemetry import collect_cache_metrics
+    from repro.kernels.dag_walk import (clear_walker_call_cache,
+                                        walker_call_cache_stats)
+    from repro.vee.apps import run_device_dag
+
+    low = _two_stage_lowering()
+    clear_walker_call_cache()
+    for _ in range(3):
+        run_device_dag(low, "STATIC")
+    assert walker_call_cache_stats() == {"hits": 2, "misses": 1, "size": 1}
+    reg = MetricsRegistry()
+    collect_cache_metrics(reg)
+    snap = reg.snapshot()
+    assert snap["counters"]["sched_walker_call_cache_hits"] == 2
+    assert snap["counters"]["sched_walker_call_cache_misses"] == 1
+    assert snap["gauges"]["sched_walker_call_cache_hit_rate"] == \
+        pytest.approx(2 / 3)
+    assert "sched_device_table_cache_hits" in snap["counters"]
+    clear_walker_call_cache()
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
