@@ -185,6 +185,7 @@ from .telemetry import (
     collect_queue_metrics,
     collect_server_metrics,
     compile_counts,
+    loop_counts,
     validate_chrome_trace,
 )
 from .task import RangeTask, tasks_from_schedule
@@ -240,5 +241,5 @@ __all__ = [
     "MetricsRegistry", "CriticalPathReport", "analyze_critical_path",
     "validate_chrome_trace", "collect_queue_metrics", "collect_cache_metrics",
     "collect_bandit_metrics", "collect_server_metrics",
-    "collect_compile_metrics", "compile_counts",
+    "collect_compile_metrics", "compile_counts", "loop_counts",
 ]

@@ -41,6 +41,8 @@ Four pieces:
     compile counters fold JAX's own ``jax.monitoring`` events (trace,
     lower, compile requests, persistent-cache loads) from the first
     traced device-DAG call on; ``collect_compile_metrics`` drains them.
+    The loop counters (``loop_counts``) count the jobs that ran a
+    fixed-point loop and the walker launches they made.
 
 ``analyze_critical_path``
     Walks the recorded span timeline backward from the last-finishing
@@ -64,6 +66,7 @@ __all__ = [
     "CriticalPathReport", "analyze_critical_path",
     "validate_chrome_trace", "DEVDAG_PHASES", "phase",
     "watch_compiles", "compile_counts", "collect_compile_metrics",
+    "count_loop", "loop_counts",
 ]
 
 # span kinds carrying real duration (the critical-path walk's alphabet);
@@ -72,7 +75,7 @@ WORK_KINDS = ("exec", "transfer")
 # host phases of the device-DAG path (``phase``)
 DEVDAG_PHASES = ("devdag.job", "devdag.merge", "devdag.tables",
                  "devdag.table_put", "devdag.launch", "devdag.readback",
-                 "devdag.combine")
+                 "devdag.combine", "devdag.feed")
 # flag bits on exec spans
 F_STOLEN = 1
 F_DEVICE = 2
@@ -387,6 +390,25 @@ def compile_counts() -> dict:
     return c
 
 
+_LOOP = {"jobs": 0, "launches": 0}
+
+
+def count_loop(jobs: int = 0, launches: int = 0) -> None:
+    """Add to the fixed-point loop counters (``loop_counts``).
+
+    ``run_device_dag`` counts a job when its lowering's loop starts and
+    the walker launches of each round (one per shard) as it walks them,
+    whatever the tracer.
+    """
+    _LOOP["jobs"] += jobs
+    _LOOP["launches"] += launches
+
+
+def loop_counts() -> dict:
+    """Process-wide ``{"jobs", "launches"}`` of fixed-point loop jobs."""
+    return dict(_LOOP)
+
+
 # --------------------------------------------------------------------------
 # Metrics
 # --------------------------------------------------------------------------
@@ -546,7 +568,8 @@ def collect_queue_metrics(reg: MetricsRegistry, counters: dict,
 
 
 def collect_cache_metrics(reg: MetricsRegistry) -> None:
-    """Lowering-memo, device-resident table and walker call hit rates (§16)."""
+    """Lowering-memo, device-resident table and walker call hit rates (§16),
+    and the fixed-point loop's jobs and launches (``loop_counts``)."""
     from .device_schedule import dag_table_cache_stats
     pairs = [("lowering_cache", dag_table_cache_stats())]
     try:
@@ -561,6 +584,11 @@ def collect_cache_metrics(reg: MetricsRegistry) -> None:
         reg.counter(f"sched_{name}_hits").inc(h)
         reg.counter(f"sched_{name}_misses").inc(m)
         reg.gauge(f"sched_{name}_hit_rate").set(h / max(1, h + m))
+    loop = loop_counts()
+    reg.counter("sched_devdag_loop_jobs", "jobs that ran a fixed-point loop"
+                ).inc(loop["jobs"])
+    reg.counter("sched_devdag_loop_launches",
+                "walker launches of fixed-point loop jobs").inc(loop["launches"])
 
 
 def collect_compile_metrics(reg: MetricsRegistry) -> None:

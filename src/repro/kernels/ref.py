@@ -12,6 +12,21 @@ def cc_propagate_ref(G: jax.Array, c: jax.Array) -> jax.Array:
     return jnp.maximum(neigh.max(axis=1), c)
 
 
+def connected_components_ref(G: jax.Array, c: jax.Array) -> tuple[jax.Array, int]:
+    """Listing 1 to its fixed point: ``cc_propagate_ref`` until no label changes.
+
+    Returns the labels and the number of rounds, counting the last one,
+    which changes nothing.
+    """
+    rounds = 0
+    while True:
+        u = cc_propagate_ref(G, c)
+        rounds += 1
+        if bool(jnp.array_equal(u, c)):
+            return u, rounds
+        c = u
+
+
 def flash_attention_ref(q, k, v, causal: bool = True):
     """q,k,v: (B, H, S, dh) (MHA; GQA expansion happens in ops)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)

@@ -45,7 +45,8 @@ __all__ = [
     "recommendation_pipeline", "recommendation_oracle",
     "recommendation_regret",
     "linear_regression_online", "recommendation_online",
-    "DeviceLowering", "run_device_dag", "linreg_device_lowering",
+    "DeviceLowering", "Fixpoint", "run_device_dag", "linreg_device_lowering",
+    "cc_device_lowering",
     "linear_regression_device", "recommendation_device_lowering",
     "recommendation_device", "linear_regression_hetero",
     "recommendation_hetero", "hetero_affinity_dag",
@@ -418,7 +419,8 @@ def recommendation_regret(items, n_users: int, n_items: int,
 
 # ---------------------------------------------------------------------------
 # device lowerings (DESIGN.md §11): the same pipelines as one fused launch
-# through build_dag_tables + the Pallas multi-stage walker
+# (one per round for a fixed-point loop) through build_dag_tables + the
+# Pallas multi-stage walker
 # ---------------------------------------------------------------------------
 
 @dataclass
@@ -440,7 +442,9 @@ class DeviceLowering:
     (any technique, one shard); the host folds in the same order when run
     with ``technique="SS"`` (one-tile chunks) and ``n_workers=1``.
     ``finalize`` maps stage values to the pipeline's answer (e.g. the
-    linreg solve).
+    linreg solve). ``fixpoint``, when set, makes a job a loop of walks
+    until a stage reads 0 (``Fixpoint``); ``dag`` and ``values`` are then
+    its first round.
     """
 
     dag: PipelineDAG
@@ -449,6 +453,24 @@ class DeviceLowering:
     values: dict
     tile: int
     finalize: object = None
+    fixpoint: Fixpoint | None = None
+
+
+@dataclass(frozen=True)
+class Fixpoint:
+    """A fixed-point loop over a lowering's super-table (DESIGN.md §11).
+
+    After each walk, ``feed`` replaces operands with stage outputs for the
+    next round: ``(operand, stage, shape)`` puts the host copy of
+    ``stage``'s output, reshaped to ``shape``, on the device as
+    ``operand`` (same dtype, so every round reuses the compiled walker).
+    The loop stops at the first round whose sum stage ``until`` reads 0,
+    and raises when ``max_rounds`` rounds have not reached it.
+    """
+
+    feed: tuple[tuple[str, str, tuple[int, ...]], ...]
+    until: str
+    max_rounds: int = 100
 
 
 def run_device_dag(
@@ -478,11 +500,18 @@ def run_device_dag(
     device-resident table cache keyed by the same signature — the table
     transfer happens once, not once per job.
 
+    A lowering with a ``fixpoint`` walks once per round, each round on
+    the job's one table (cached device table, memoized walker) with the
+    previous round's outputs fed into its operands, and returns the last
+    round's values (``Fixpoint``); the stagewise baseline walks once.
+
     The call is the ``devdag.job`` phase (DESIGN.md §18) of ``tracer``
     under ``job``; inside it ``devdag.tables`` (signature, table build,
-    scaling to rows) and the walker's own phases. A profiler trace holds
-    them whatever ``tracer`` is; an enabled tracer also records them and
-    starts the compile counters (``core.telemetry.watch_compiles``).
+    scaling to rows), the walker's own phases for each round and, after
+    each round that did not converge, ``devdag.feed`` (the fed-back
+    operands put on the device). A profiler trace holds them whatever
+    ``tracer`` is; an enabled tracer also records them and starts the
+    compile counters (``core.telemetry.watch_compiles``).
     """
     from ..core.device_schedule import build_dag_tables_cached, dag_signature
     from ..core.telemetry import as_tracer, phase, watch_compiles
@@ -491,6 +520,8 @@ def run_device_dag(
     tracer = as_tracer(tracer)
     if tracer.enabled:
         watch_compiles()
+    if stagewise and (n_shards != 1 or lowering.fixpoint is not None):
+        raise ValueError("stagewise baseline runs single-shard, one walk")
     with phase(tracer, "devdag.job", job):
         with phase(tracer, "devdag.tables", job):
             key = dag_signature(
@@ -501,20 +532,47 @@ def run_device_dag(
                 n_workers=n_workers, chunk_costs=chunk_costs, seed=seed)
             rows = ddt.tables.copy()
             rows[:, :, 1:] *= lowering.tile  # tile units -> row space
-        if stagewise:
-            if n_shards != 1:
-                raise ValueError("stagewise baseline runs single-shard")
-            out = dag_walk_stagewise(lowering.stages, lowering.operands,
-                                     lowering.values, rows[0], lowering.tile)
-        else:
-            # an untraced call keeps the walker's plain signature, so a
-            # wrapper of dag_walk_sharded (fault injection) sees no change
-            traced = {"tracer": tracer, "job": job} if tracer.enabled else {}
-            out = dag_walk_sharded(lowering.stages, lowering.operands,
-                                   lowering.values, rows, lowering.tile,
-                                   table_key=("devdag", lowering.tile, key),
-                                   **traced)
-        return {k: np.asarray(v) for k, v in out.items()}, ddt
+
+        def walk(values):
+            if stagewise:
+                out = dag_walk_stagewise(lowering.stages, lowering.operands,
+                                         values, rows[0], lowering.tile)
+            else:
+                # an untraced call keeps the walker's plain signature, so a
+                # wrapper of dag_walk_sharded (fault injection) sees no change
+                traced = {"tracer": tracer, "job": job} if tracer.enabled else {}
+                out = dag_walk_sharded(lowering.stages, lowering.operands,
+                                       values, rows, lowering.tile,
+                                       table_key=("devdag", lowering.tile, key),
+                                       **traced)
+            return {k: np.asarray(v) for k, v in out.items()}
+
+        if lowering.fixpoint is None:
+            return walk(lowering.values), ddt
+        return _walk_to_fixpoint(lowering.fixpoint, walk, lowering.values,
+                                 n_shards, tracer, job), ddt
+
+
+def _walk_to_fixpoint(loop: Fixpoint, walk, values: dict, n_shards: int,
+                      tracer, job: str) -> dict:
+    """Walk rounds until ``loop.until`` reads 0; the last round's values."""
+    import jax.numpy as jnp
+
+    from ..core.telemetry import count_loop, phase
+
+    count_loop(jobs=1)
+    for _ in range(loop.max_rounds):
+        out = walk(values)
+        count_loop(launches=n_shards)
+        if not out[loop.until].any():
+            return out
+        with phase(tracer, "devdag.feed", job):
+            values = dict(values)
+            for operand, stage, shape in loop.feed:
+                values[operand] = jnp.asarray(out[stage].reshape(shape))
+    raise RuntimeError(
+        f"no fixed point after {loop.max_rounds} rounds: {loop.until!r} "
+        f"still reads {out[loop.until].sum()}")
 
 
 def merge_device_lowerings(lowerings: list[DeviceLowering], tracer=None,
@@ -562,6 +620,9 @@ def _merge_device_lowerings(lowerings: list[DeviceLowering]) -> DeviceLowering:
 
     if not lowerings:
         raise ValueError("cannot merge an empty batch of lowerings")
+    if any(low.fixpoint is not None for low in lowerings):
+        # members may converge after different numbers of rounds
+        raise ValueError("cannot merge lowerings with a fixed-point loop")
     tiles = {low.tile for low in lowerings}
     if len(tiles) != 1:
         raise ValueError(f"cannot merge lowerings with mixed tiles {tiles}")
@@ -688,6 +749,112 @@ def _user_bias_body(ctx, ins, out):
 def _scores_body(ctx, ins, out):
     out[...] = _scores_tile(ins["R"][...], ins["item_norms"][...],
                             ins["user_bias"][...])
+
+
+def _propagate_rows(Gb, c_col, c_row):
+    """Listing 1's propagation for a block of rows: each row's largest label
+    over itself and its neighbours (``kernels.ref.cc_propagate_ref``)."""
+    import jax.numpy as jnp
+
+    return jnp.maximum(c_row, jnp.where(Gb > 0, c_col, 0.0).max(axis=1, keepdims=True))
+
+
+def _flips_tile(u, c_row):
+    """How many labels of a row tile the round changed, as ``(1, 1)`` int32."""
+    import jax.numpy as jnp
+
+    return (u != c_row).astype(jnp.int32).sum(axis=0, keepdims=True)
+
+
+def _cc_propagate_body(ctx, ins, out):
+    from ..kernels.cc_propagate import propagate_body
+
+    propagate_body(ctx.inner, ins["G"], ins["c_col"], ins["c_row"], out)
+
+
+def _cc_changed_body(ctx, ins, out):
+    out[...] += _flips_tile(ins["propagate"][...], ins["c_row"][...])
+
+
+def cc_device_lowering(
+    scale: int,
+    edge_factor: int = 16,
+    tile: int = 256,
+    tile_c: int = 1024,
+    seed: int = 0,
+) -> DeviceLowering:
+    """Paper Listing 1 lowered for the fused walker, run to its fixed point.
+
+    The graph is Graph500's Kronecker graph (``vee.sparse.rmat_graph``
+    with its initiator, ids permuted): ``n = 2**scale`` vertices,
+    ``edge_factor * n`` generated edges made undirected, self-loops
+    dropped. The device holds its dense float32 ``(n, n)`` adjacency ``G``,
+    put there once, here. One round is a two-stage super-table:
+    ``propagate`` (concat, ``(n, 1)``) is ``kernels.cc_propagate``'s
+    ``propagate_body`` over the ``n // tile_c`` column tiles of the
+    walker's inner axis; ``changed`` (sum, ``(1, 1)`` int32) counts each
+    row tile's label flips, reading ``propagate``'s rows. Labels start at
+    1..n in float32 (exact to 2**24) as ``c_row`` ``(n, 1)`` and ``c_col``
+    ``(1, n)``; the ``Fixpoint`` feeds ``propagate`` back into both until
+    ``changed`` reads 0, at most Listing 1's 100 rounds. ``finalize``
+    returns the labels as int64: each vertex's largest 1-based id in its
+    component. The host DAG is the first round, its ops the same row
+    math as the bodies.
+    """
+    import jax.numpy as jnp
+
+    from ..kernels.dag_walk import WalkOperand, WalkStage
+    from .sparse import rmat_graph
+
+    n = 1 << scale
+    if n % tile or n % tile_c:
+        raise ValueError(f"n={n} must be a multiple of tile={tile} and "
+                         f"tile_c={tile_c}")
+    G = rmat_graph(scale, edge_factor, seed=seed, relabel=True).to_dense()
+    c = np.arange(1, n + 1, dtype=np.float32)
+    units = n // tile
+
+    def propagate_op(inputs, s, z):
+        return jnp.stack([
+            _propagate_rows(jnp.asarray(G[t * tile:(t + 1) * tile]), c[None, :],
+                            c[t * tile:(t + 1) * tile, None])
+            for t in range(s, s + z)])
+
+    def changed_op(inputs, s, z):
+        acc = None
+        for t in range(s, s + z):
+            v = _flips_tile(jnp.asarray(inputs["propagate"][t]),
+                            c[t * tile:(t + 1) * tile, None])
+            acc = v if acc is None else acc + v
+        return acc
+
+    dag = PipelineDAG([
+        Stage("propagate", units, propagate_op, combine="concat"),
+        Stage("changed", units, changed_op, combine="sum",
+              deps=(StageDep("propagate", DEP_ELEMENTWISE),)),
+    ])
+    stages = [
+        WalkStage("propagate", n, (n, 1), jnp.float32, "concat",
+                  _cc_propagate_body, operands=("G", "c_col", "c_row"),
+                  inner=n // tile_c),
+        WalkStage("changed", n, (1, 1), jnp.int32, "sum", _cc_changed_body,
+                  operands=("c_row",), reads=(("propagate", "rows"),)),
+    ]
+    operands = [
+        WalkOperand("G", (tile, tile_c), ("row", "inner")),
+        WalkOperand("c_col", (1, tile_c), ("zero", "inner")),
+        WalkOperand("c_row", (tile, 1), ("row", "zero")),
+    ]
+    values = {"G": jnp.asarray(G), "c_col": jnp.asarray(c.reshape(1, n)),
+              "c_row": jnp.asarray(c.reshape(n, 1))}
+    loop = Fixpoint(feed=(("c_row", "propagate", (n, 1)),
+                          ("c_col", "propagate", (1, n))),
+                    until="changed")
+
+    def finalize(stage_values: dict) -> np.ndarray:
+        return np.asarray(stage_values["propagate"]).reshape(-1).astype(np.int64)
+
+    return DeviceLowering(dag, stages, operands, values, tile, finalize, loop)
 
 
 def linreg_device_lowering(

@@ -529,7 +529,8 @@ def test_profiler_trace_holds_devdag_spans(tmp_path):
     host = [e for plane in data.planes if plane.name.startswith("/host:")
             for line in plane.lines for e in line.events
             if e.name.startswith("devdag.")]
-    want = set(DEVDAG_PHASES) - {"devdag.merge"}
+    # a single launch: no merge, and no fixed-point loop to feed
+    want = set(DEVDAG_PHASES) - {"devdag.merge", "devdag.feed"}
     assert {e.name for e in host} == want
     (job,) = [e for e in host if e.name == "devdag.job"]
     assert all(job.start_ns <= e.start_ns and e.end_ns <= job.end_ns
@@ -591,6 +592,31 @@ def test_cache_metrics_export_walker_call_cache():
         pytest.approx(2 / 3)
     assert "sched_device_table_cache_hits" in snap["counters"]
     clear_walker_call_cache()
+
+
+def test_cache_metrics_export_loop_counters():
+    """A fixed-point loop job's count and its launches reach the registry;
+    a one-walk job adds to neither."""
+    from repro.core.telemetry import collect_cache_metrics, loop_counts
+    from repro.vee.apps import cc_device_lowering, run_device_dag
+
+    def exported():
+        reg = MetricsRegistry()
+        collect_cache_metrics(reg)
+        counters = reg.snapshot()["counters"]
+        return (counters["sched_devdag_loop_jobs"],
+                counters["sched_devdag_loop_launches"])
+
+    start = exported()
+    assert start == (loop_counts()["jobs"], loop_counts()["launches"])
+    run_device_dag(_two_stage_lowering(), "STATIC")
+    assert exported() == start
+    tracer = Tracer()
+    run_device_dag(cc_device_lowering(8, 16, tile=64, tile_c=128, seed=2),
+                   {"propagate": "MFSC", "changed": "STATIC"}, tracer=tracer)
+    launches = sum(s.kind == "devdag.launch" for s in tracer.spans())
+    assert launches >= 2
+    assert exported() == (start[0] + 1, start[1] + launches)
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ and that the program fits one chip's 16 GiB. The sizes are those of
 ``chip_smoke.py``: the paper's linear regression at 2,097,152 x 101, the
 recommendation pipeline at 65,536 users x 4,096 items, the §14 batch of
 three 262,144-row regressions, and CC propagation on a 16,384-node dense
-adjacency.
+adjacency, alone and as one round of the CC walker (its inner axis over
+16 column tiles).
 
 The topology is described inside a module fixture, never while a module is
 imported: only one process at a time may load the TPU library, and the
@@ -32,7 +33,7 @@ TEMP_LIMIT = 64 * 2**20
 LINREG_ROWS, LINREG_COLS, LINREG_TILE = 2_097_152, 101, 2048
 BATCH_ROWS, BATCH_MEMBERS = 262_144, 3
 REC_USERS, REC_ITEMS, REC_TILE = 65_536, 4_096, 128
-CC_N = 16_384
+CC_N, CC_TILE, CC_TILE_C = 16_384, 256, 1024
 
 
 @pytest.fixture(scope="module")
@@ -133,3 +134,16 @@ def test_cc_propagate_compiles(one_chip):
              _sds(one_chip, (CC_N,), jnp.float32),
              _sds(one_chip, (CC_N // DEFAULT_TILE_R,), jnp.int32),
              kernel="cc_propagate")
+
+
+def test_walker_cc_round_compiles(one_chip):
+    from repro.vee.apps import cc_device_lowering
+
+    # the stages and operand specs of a small graph, resized to full size
+    low = cc_device_lowering(10, 16, tile=CC_TILE, tile_c=CC_TILE_C)
+    stages = [dataclasses.replace(s, n_rows=CC_N, out_shape=(CC_N, 1),
+                                  inner=CC_N // CC_TILE_C)
+              if s.name == "propagate" else dataclasses.replace(s, n_rows=CC_N)
+              for s in low.stages]
+    _walker(one_chip, low, {"G": (CC_N, CC_N), "c_col": (1, CC_N),
+                            "c_row": (CC_N, 1)}, CC_N, stages=stages)
