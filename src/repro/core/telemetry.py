@@ -569,14 +569,23 @@ def collect_queue_metrics(reg: MetricsRegistry, counters: dict,
 
 def collect_cache_metrics(reg: MetricsRegistry) -> None:
     """Lowering-memo, device-resident table and walker call hit rates (§16),
-    and the fixed-point loop's jobs and launches (``loop_counts``)."""
+    the walker's held operand steps (``walker_hold_stats``, §11) and the
+    fixed-point loop's jobs and launches (``loop_counts``)."""
     from .device_schedule import dag_table_cache_stats
     pairs = [("lowering_cache", dag_table_cache_stats())]
     try:
         from ..kernels.dag_walk import (device_table_cache_stats,
-                                        walker_call_cache_stats)
+                                        walker_call_cache_stats,
+                                        walker_hold_stats)
         pairs.append(("device_table_cache", device_table_cache_stats()))
         pairs.append(("walker_call_cache", walker_call_cache_stats()))
+        hold = walker_hold_stats()
+        reg.counter("sched_walker_held_steps",
+                    "walker grid steps that held an inner-indexed operand's "
+                    "block").inc(hold["held_steps"])
+        reg.counter("sched_walker_inner_steps",
+                    "walker grid steps x inner-indexed operands"
+                    ).inc(hold["inner_steps"])
     except Exception:  # pragma: no cover - kernels unavailable
         pass
     for name, st in pairs:

@@ -14,7 +14,9 @@ removed. This module executes a whole pipeline-DAG super-table
   ``WalkStage`` contributes a body over refs (cc_propagate's
   ``propagate_body`` is the single-stage special case);
 * operand block index maps read the slot's row range from the table
-  (clamped), so every input block follows the schedule;
+  (clamped), so every input block follows the schedule; an operand on the
+  inner axis that one stage reads holds its block at steps that do not
+  read it, so e.g. a CC round's ``changed`` slots fetch no adjacency;
 * a TPU never reads an output block back from HBM: it writes a block
   back when the block index moves on, and a block it has left must not
   be visited again. So a concat stage's output block follows its OWN
@@ -55,7 +57,8 @@ from .mode import pallas_call
 __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
            "dag_walk_stagewise", "dag_walk_sharded",
            "device_table_cache_stats", "clear_device_table_cache",
-           "walker_call_cache_stats", "clear_walker_call_cache"]
+           "walker_call_cache_stats", "clear_walker_call_cache",
+           "walker_hold_stats"]
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +72,7 @@ __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
 # caller reuses a key for a rebalanced table.
 # ---------------------------------------------------------------------------
 
-_DEVICE_TABLE_CACHE: dict[tuple, jax.Array] = {}
+_DEVICE_TABLE_CACHE: dict[tuple, tuple[jax.Array, np.ndarray]] = {}
 _DEVICE_TABLE_STATS = {"hits": 0, "misses": 0}
 
 
@@ -118,17 +121,52 @@ def clear_walker_call_cache() -> None:
 def _jitted_walker(stages: tuple[WalkStage, ...],
                    operands: tuple[WalkOperand, ...],
                    shapes: tuple[tuple[tuple[int, ...], Any], ...],
-                   n_slots: int, tile: int) -> Callable:
-    """One ``jax.jit`` of ``walk_call`` per structure.
+                   n_slots: int, tile: int) -> tuple[Callable, tuple]:
+    """One ``jax.jit`` of ``walk_call`` per structure, with its hold plan.
 
     Stages and operands are frozen dataclasses, so the key compares them
     by value; a stage body compares as the body itself does (by identity
     for a plain function, by its fields for a frozen-dataclass body).
     ``shapes`` holds each operand's ``(shape, dtype)`` in operand order.
+    The hold plan is ``(inner steps per slot, inner-indexed operands,
+    ((reader stage, its inner), ...) of the held ones)``.
     """
-    return jax.jit(walk_call(list(stages), list(operands),
+    call = jax.jit(walk_call(list(stages), list(operands),
                              {op.name: shape for op, (shape, _) in zip(operands, shapes)},
                              n_slots, tile))
+    plan = (max(s.inner for s in stages),
+            sum("inner" in op.index for op in operands),
+            tuple(_held_operands(list(stages), list(operands)).values()))
+    return call, plan
+
+
+# ---------------------------------------------------------------------------
+# operand hold counters (DESIGN.md §11)
+#
+# Grid steps at which an inner-indexed operand's block was held
+# (``_index_map``), and grid steps times inner-indexed operands, summed over
+# launches. Counted from the launch's slot count and its table's live slots
+# per stage, which ``_device_table`` counts once per distinct table.
+# ---------------------------------------------------------------------------
+
+_WALKER_HOLD_STATS = {"held_steps": 0, "inner_steps": 0}
+
+
+def walker_hold_stats() -> dict:
+    """Walker hold counters: ``{"held_steps", "inner_steps"}``."""
+    return dict(_WALKER_HOLD_STATS)
+
+
+def _count_held_steps(plan: tuple, live: np.ndarray, n_slots: int) -> None:
+    """Add one launch's steps to ``walker_hold_stats``: a held operand's
+    reader reads it at ``inner`` steps of each of its live slots."""
+    n_inner, n_inner_ops, held = plan
+    if not n_inner_ops:
+        return
+    steps = n_slots * n_inner
+    _WALKER_HOLD_STATS["inner_steps"] += steps * n_inner_ops
+    _WALKER_HOLD_STATS["held_steps"] += sum(steps - int(live[k]) * inner
+                                            for k, inner in held)
 
 
 def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
@@ -165,8 +203,10 @@ def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
     return np.ascontiguousarray(np.concatenate([table, cur], axis=1))
 
 
-def _device_table(table: np.ndarray, key: tuple | None) -> jax.Array:
-    """Device-resident copy of a walker table (``_walk_table``), flattened.
+def _device_table(table: np.ndarray, key: tuple | None
+                  ) -> tuple[jax.Array, np.ndarray]:
+    """Device-resident copy of a walker table (``_walk_table``), flattened,
+    and the table's live slot count per stage (for ``walker_hold_stats``).
 
     Unkeyed: a plain ``jax.device_put`` — async dispatch, so issuing it
     for shard ``s+1`` before walking shard ``s`` double-buffers the
@@ -175,20 +215,26 @@ def _device_table(table: np.ndarray, key: tuple | None) -> jax.Array:
     handoff — the walker reads the cached buffer directly). The host
     array is never mutated afterwards (build_dag_tables_cached marks it
     read-only), so ``may_alias`` lets same-device backends alias the
-    host buffer instead of copying.
+    host buffer instead of copying. The live counts are counted with the
+    put, so a keyed table counts them once.
     """
-    table = table.reshape(-1)
+    flat = table.reshape(-1)
+
+    def put():
+        live = np.bincount(table[:, 0][table[:, 2] > 0],
+                           minlength=table.shape[1] - 3)
+        return jax.device_put(flat, may_alias=True), live
+
     if key is None:
-        return jax.device_put(table, may_alias=True)
-    ck = (key, table.shape, table.tobytes())
-    dev = _DEVICE_TABLE_CACHE.get(ck)
-    if dev is not None:
+        return put()
+    ck = (key, flat.shape, flat.tobytes())
+    hit = _DEVICE_TABLE_CACHE.get(ck)
+    if hit is not None:
         _DEVICE_TABLE_STATS["hits"] += 1
-        return dev
+        return hit
     _DEVICE_TABLE_STATS["misses"] += 1
-    dev = jax.device_put(table, may_alias=True)
-    _DEVICE_TABLE_CACHE[ck] = dev
-    return dev
+    _DEVICE_TABLE_CACHE[ck] = put()
+    return _DEVICE_TABLE_CACHE[ck]
 
 
 @dataclass(frozen=True)
@@ -260,28 +306,66 @@ class WalkCtx:
 
 
 def _index_map(block: tuple[int, ...], kinds: tuple[str, ...],
-               shape: tuple[int, ...], width: int, start_col: int = 1):
+               shape: tuple[int, ...], width: int, start_col: int = 1,
+               reader: tuple[int, int] | None = None):
     """Block index map for one buffer: row tile / inner / constant.
 
     ``row`` axes follow the row start in column ``start_col`` of the
     flattened ``width``-wide walker table: the slot's own start (1) for
     operands, the stage's current start (3 + k) for stage k's output.
+
+    ``reader`` is ``(k, inner)`` of the one stage that reads an operand
+    with an ``inner`` axis (``_held_operands``). A grid step reads the
+    operand when its slot is one of stage k's, is not empty and ``j`` is
+    below ``inner``; at any other step the block holds on stage k's latest
+    reading block: its current start (column ``3 + k``) and its last inner
+    block. A TPU pipeline copies an input block only when its index moves,
+    so a held block costs no copy, and no body reads it there.
     """
     nb = [max(1, shape[a] // block[a]) for a in range(len(block))]
 
     def imap(i, j, tbl):
+        col, inner_j = start_col, j
+        if reader is not None:
+            k, inner = reader
+            reads = ((tbl[width * i] == k) & (j < inner)
+                     & (tbl[width * i + 2] > 0))
+            col = jnp.where(reads, start_col, 3 + k)
+            inner_j = jnp.where(reads, j, inner - 1)
         out = []
         for a, kind in enumerate(kinds):
             if kind == "row":
-                out.append(jnp.minimum(tbl[width * i + start_col] // block[a],
+                out.append(jnp.minimum(tbl[width * i + col] // block[a],
                                        nb[a] - 1))
             elif kind == "inner":
-                out.append(jnp.minimum(j, nb[a] - 1))
+                out.append(jnp.minimum(inner_j, nb[a] - 1))
             else:
                 out.append(0)
         return tuple(out)
 
     return imap
+
+
+def _held_operands(stages: list[WalkStage], operands: list[WalkOperand]
+                   ) -> dict[str, tuple[int, int]]:
+    """``{operand: (reader stage id, its inner)}`` for each operand whose
+    block holds at steps that do not read it (``_index_map``).
+
+    Only an operand with an ``inner`` axis that exactly one stage reads is
+    held: the other kinds move with the slot's row tile, which the stages'
+    own slots step through anyway, so their maps stay as they are. An
+    inner-indexed operand read by several stages keeps the plain map too
+    (no lowering has one).
+    """
+    held = {}
+    for op in operands:
+        if "inner" not in op.index:
+            continue
+        readers = [k for k, s in enumerate(stages)
+                   if op.name in s.operands or op.name in (p for p, _ in s.reads)]
+        if len(readers) == 1:
+            held[op.name] = (readers[0], stages[readers[0]].inner)
+    return held
 
 
 def _read_operand(stages_by_name: dict[str, WalkStage], prod: str, kind: str,
@@ -329,12 +413,16 @@ def walk_call(
     Returns ``f(table, *operand_values)``, where ``table`` is the walker
     table (``_walk_table``) flattened to 1-D int32 (scalar memory pads a
     2-D table's rows to 128 lanes), giving one output per stage, in
-    ``stages`` order. The kernel is named ``KERNEL_NAME``.
+    ``stages`` order. The kernel is named ``KERNEL_NAME``. An operand with
+    an ``inner`` axis that one stage reads holds its block at the steps
+    that do not read it (``_index_map``, ``_held_operands``).
     """
     n_inner = max(s.inner for s in stages)
     width = 3 + len(stages)
+    held = _held_operands(stages, operands)
     in_specs = [pl.BlockSpec(op.block, _index_map(op.block, op.index,
-                                                  shapes[op.name], width))
+                                                  shapes[op.name], width,
+                                                  reader=held.get(op.name)))
                 for op in operands]
     out_specs, out_shapes = [], []
     for k, s in enumerate(stages):
@@ -385,7 +473,7 @@ def dag_walk(
     table: np.ndarray,
     tile: int,
     table_key: tuple | None = None,
-    _dev_table: jax.Array | None = None,
+    _dev_table: tuple[jax.Array, np.ndarray] | None = None,
     tracer: Tracer | None = None,
     job: str = "",
     shard: int = 0,
@@ -398,7 +486,7 @@ def dag_walk(
     multi-shard table a shard only fills the tiles it owns (combine with
     ``dag_walk_sharded``). ``table_key`` keeps the transferred table
     device-resident across launches (see ``_device_table``);
-    ``_dev_table`` is a pre-transferred device array from
+    ``_dev_table`` is a pre-transferred ``_device_table`` result from
     ``dag_walk_sharded``'s double-buffered prefetch.
 
     The kernel call is jitted once per structure (stages, operands, their
@@ -408,7 +496,8 @@ def dag_walk(
     traces nor lowers. The launch (the memo lookup and the dispatch, plus
     on a miss the trace, lowering and compile or cache load) is the
     ``devdag.launch`` phase of ``tracer`` under ``job``, tagged
-    ``shard=<shard>`` (DESIGN.md §18).
+    ``shard=<shard>`` (DESIGN.md §18). Each launch adds its steps to
+    ``walker_hold_stats``.
     """
     table = np.ascontiguousarray(np.asarray(table, dtype=np.int32))
     if table.ndim != 2 or table.shape[1] != 3:
@@ -422,17 +511,18 @@ def dag_walk(
 
     tracer = as_tracer(tracer)
     tag = f"shard={shard}"
-    tbl_dev = _dev_table
-    if tbl_dev is None:
+    if _dev_table is None:
         with phase(tracer, "devdag.table_put", job, tag):
-            tbl_dev = _device_table(_walk_table(table, stages), table_key)
+            _dev_table = _device_table(_walk_table(table, stages), table_key)
+    tbl_dev, live = _dev_table
     with phase(tracer, "devdag.launch", job, tag):
-        call = _jitted_walker(
+        call, plan = _jitted_walker(
             tuple(stages), tuple(operands),
             tuple((tuple(values[op.name].shape), values[op.name].dtype)
                   for op in operands),
             n_slots, tile)
         out = call(tbl_dev, *[values[op.name] for op in operands])
+    _count_held_steps(plan, live, n_slots)
     return {s.name: o for s, o in zip(stages, out)}
 
 

@@ -8,7 +8,10 @@ graphs at scale 9 (n = 512, tiles 128 x 256) and scale 8 (n = 256, tiles
 32 x 64) the loop's labels and its round count equal the plain
 ``kernels.ref.connected_components_ref`` exactly (a max over labels has
 no rounding), with one shard and two, and the labels equal the host
-pipeline-DAG path's. Rounds after the first and later jobs of the same
+pipeline-DAG path's. At scale 10 the loop's labels equal a union-find's,
+and one round, whose ``changed`` slots hold the adjacency's block
+(``kernels.dag_walk._index_map``), equals the one-launch-per-stage walk
+bit for bit. Rounds after the first and later jobs of the same
 shape reuse the compiled walker. Everything runs on the CPU, in Pallas's
 TPU interpret mode.
 """
@@ -22,6 +25,7 @@ import pytest
 
 from repro.core import (PipelineExecutor, SchedulerConfig, Tracer,
                         compile_counts, loop_counts)
+from repro.core.device_schedule import build_dag_tables_cached
 from repro.kernels import dag_walk as dw
 from repro.kernels import ref
 from repro.vee.apps import (cc_device_lowering, connected_components_dag,
@@ -70,6 +74,53 @@ def test_loop_equals_reference_labels_and_rounds(size, seed, n_shards):
         rmat_graph(scale, EDGE_FACTOR, seed=seed, relabel=True),
         SchedulerConfig(n_workers=2))
     assert np.array_equal(labels, host) and iters == rounds
+
+
+def _union_find_labels(G):
+    """Each vertex's largest 1-based id in its component, by union-find over
+    the nonzero entries of the dense adjacency."""
+    n = len(G)
+    parent = list(range(n))
+
+    def root(v):
+        while parent[v] != v:
+            parent[v] = parent[parent[v]]
+            v = parent[v]
+        return v
+
+    for u, v in zip(*np.nonzero(G)):
+        ru, rv = root(int(u)), root(int(v))
+        if ru != rv:
+            parent[min(ru, rv)] = max(ru, rv)
+    roots = np.array([root(v) for v in range(n)])
+    labels = np.zeros(n, np.int64)
+    np.maximum.at(labels, roots, np.arange(1, n + 1))
+    return labels[roots]
+
+
+def test_round_with_held_blocks_equals_stagewise_bit_for_bit():
+    """One round's ``propagate`` and ``changed`` through the fused walker,
+    whose ``changed`` slots hold G's and ``c_col``'s blocks, equal the
+    one-launch-per-stage walk's, where no slot holds a block."""
+    low = cc_device_lowering(10, EDGE_FACTOR, tile=32, tile_c=128, seed=3)
+    ddt = build_dag_tables_cached(low.dag, 1, TECHNIQUES)
+    rows = ddt.tables[0].copy()
+    rows[:, 1:] *= low.tile
+    fused = dw.dag_walk(low.stages, low.operands, low.values, rows, low.tile)
+    stagewise = dw.dag_walk_stagewise(low.stages, low.operands, low.values,
+                                      rows, low.tile)
+    assert set(fused) == set(stagewise) == {"propagate", "changed"}
+    for k in fused:
+        assert np.array_equal(np.asarray(fused[k]), np.asarray(stagewise[k])), k
+    assert int(np.asarray(fused["changed"]).sum()) > 0
+
+
+def test_scale10_fixed_point_equals_union_find():
+    low = cc_device_lowering(10, EDGE_FACTOR, tile=128, tile_c=256, seed=1)
+    labels, launches = _run(low)
+    want = _union_find_labels(np.asarray(low.values["G"]))
+    assert np.array_equal(labels, want)
+    assert launches >= 2
 
 
 def test_later_rounds_and_jobs_reuse_the_compiled_walker():

@@ -619,6 +619,36 @@ def test_cache_metrics_export_loop_counters():
     assert exported() == (start[0] + 1, start[1] + launches)
 
 
+def test_cache_metrics_export_walker_hold_share():
+    """A CC job's launches hold the adjacency and column-label blocks at
+    half their steps (the ``changed`` slots'); a linreg job, whose operands
+    have no inner axis, adds 0 of 0."""
+    from repro.core.telemetry import collect_cache_metrics
+    from repro.vee.apps import (cc_device_lowering, linreg_device_lowering,
+                                run_device_dag)
+
+    def exported():
+        reg = MetricsRegistry()
+        collect_cache_metrics(reg)
+        counters = reg.snapshot()["counters"]
+        return (counters["sched_walker_held_steps"],
+                counters["sched_walker_inner_steps"])
+
+    start = exported()
+    run_device_dag(_two_stage_lowering(), "STATIC")
+    assert exported() == start
+    run_device_dag(linreg_device_lowering(256, 5, tile=32), "GSS")
+    assert exported() == start
+    tracer = Tracer()
+    run_device_dag(cc_device_lowering(8, 16, tile=64, tile_c=128, seed=2),
+                   {"propagate": "MFSC", "changed": "STATIC"}, tracer=tracer)
+    launches = sum(s.kind == "devdag.launch" for s in tracer.spans())
+    held, inner = (b - a for a, b in zip(start, exported()))
+    # per launch: 8 slots x 2 inner steps x 2 operands (G, c_col)
+    assert inner == 32 * launches and launches >= 2
+    assert held / inner == 0.5
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
