@@ -24,7 +24,7 @@ from repro.vee.apps import linear_regression_oracle, linreg_device_lowering
 # `embed` wants the accelerator; `join` consumes both elementwise. The
 # transfer term makes naive per-stage greedy ping-pong expensive — the
 # solver keeps branches substrate-resident and overlaps them. (This is the
-# same workload the hetero_linreg_placement CI gate scores.)
+# same workload tests/test_placement.py scores.)
 dag, costs = hetero_affinity_dag(4096)
 
 placement, hetero_ms, base = select_placement(dag, costs, n_workers=8)

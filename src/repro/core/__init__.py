@@ -81,7 +81,6 @@ from .device_schedule import (
     build_dag_tables,
     build_dag_tables_cached,
     build_task_table,
-    clear_dag_table_cache,
     cost_balanced_assignment,
     dag_signature,
     dag_table_cache_stats,
@@ -149,7 +148,6 @@ from .preempt import (
     run_device_prefix,
 )
 from .queues import (
-    QUEUE_IMPLS,
     QUEUE_LAYOUTS,
     CentralizedQueue,
     DistributedQueues,
@@ -194,7 +192,7 @@ from .victim import VICTIM_STRATEGIES, VictimSelector, make_victim_selector
 __all__ = [
     "PARTITIONERS", "Partitioner", "chunk_schedule", "chunk_sizes",
     "first_chunk", "first_chunk_fn", "make_partitioner",
-    "QUEUE_LAYOUTS", "QUEUE_IMPLS", "CentralizedQueue", "DistributedQueues",
+    "QUEUE_LAYOUTS", "CentralizedQueue", "DistributedQueues",
     "SlotCentralizedQueue", "SlotDistributedQueues",
     "VICTIM_STRATEGIES", "VictimSelector", "make_victim_selector",
     "RangeTask", "tasks_from_schedule",
@@ -214,7 +212,6 @@ __all__ = [
     "cost_balanced_assignment",
     "DeviceDagTables", "build_dag_tables", "rebalance_dag",
     "dag_signature", "build_dag_tables_cached", "dag_table_cache_stats",
-    "clear_dag_table_cache",
     "select_offline", "OnlineTuner", "default_search_space",
     "select_offline_dag", "DagTuner", "select_offline_server",
     "select_offline_device_dag",

@@ -33,8 +33,8 @@ is the layer in front of the pool:
                             timestamped arrivals, admission/batching/
                             autoscaling decisions made with LIVE engine
                             state, reporting p50/p99/p99.9 latency, shed
-                            rate, and deadline hit-rate (the
-                            ``pipeline_server_openloop`` CI gate).
+                            rate, and deadline hit-rate
+                            (``tests/test_admission.py``).
   ``heavy_tailed_trace``    the seeded open-loop workload generator:
                             Pareto interarrivals and service weights
                             over a small set of recurring pipeline
@@ -46,7 +46,8 @@ is the layer in front of the pool:
                             batch.
 
 Decisions are deterministic given the trace (the virtual clock drives
-everything), which is what lets CI gate p99.9 to a committed baseline.
+everything), which is what lets a test hold p99.9 and the deadline
+hit-rate against the FIFO baseline on the same trace.
 """
 
 from __future__ import annotations

@@ -264,8 +264,8 @@ def select_offline_hetero(
     the all-HOST and all-DEVICE baselines with ``simulate_hetero_dag``,
     then coordinate-descends per stage over {HOST, DEVICE, SPLIT(f)}
     accepting only improvements — so the returned placement is never
-    worse than min(host-only, device-only) by construction (the
-    ``hetero_linreg_placement`` CI gate). ``costs`` is a
+    worse than min(host-only, device-only) by construction
+    (``tests/test_placement.py``). ``costs`` is a
     ``HeteroCostModel`` (see ``calibrate_hetero_costs``) or a plain
     per-row dict applied to both substrates. Returns
     ``(placement, makespan, baselines)``.
@@ -411,10 +411,11 @@ class OnlineTuneResult:
     """Outcome of one ``tune_online_dag`` feedback-loop run.
 
     ``assign`` is the converged per-stage combo map, ``makespan`` its
-    simulated makespan (the "online-tuned" number the CI gate compares
-    against the offline search), ``history`` the per-round OnlineRound
-    records, and ``online`` the trained OnlineScheduler — hand it to a
-    PipelineExecutor/PipelineServer to keep learning on the real pool.
+    simulated makespan (the "online-tuned" number ``tests/test_online.py``
+    compares against the offline search), ``history`` the per-round
+    OnlineRound records, and ``online`` the trained OnlineScheduler —
+    hand it to a PipelineExecutor/PipelineServer to keep learning on the
+    real pool.
     """
 
     assign: dict[str, tuple[str, str, str]]

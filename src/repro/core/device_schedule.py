@@ -43,7 +43,6 @@ __all__ = [
     "dag_signature",
     "build_dag_tables_cached",
     "dag_table_cache_stats",
-    "clear_dag_table_cache",
     "rebalance_dag",
 ]
 
@@ -518,13 +517,6 @@ def build_dag_tables_cached(
 def dag_table_cache_stats() -> dict:
     """Lowering-cache counters: ``{"hits", "misses", "size"}``."""
     return {**_DAG_TABLE_STATS, "size": len(_DAG_TABLE_CACHE)}
-
-
-def clear_dag_table_cache() -> None:
-    """Drop cached lowerings and reset the hit/miss counters."""
-    _DAG_TABLE_CACHE.clear()
-    _DAG_TABLE_STATS["hits"] = 0
-    _DAG_TABLE_STATS["misses"] = 0
 
 
 def rebalance_dag(

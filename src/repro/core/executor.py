@@ -17,9 +17,7 @@ import time
 from dataclasses import dataclass, field
 
 from .online import ChunkObservation
-from .partitioners import make_partitioner
-from .queues import (CentralizedQueue, DistributedQueues, QUEUE_IMPLS,
-                     SlotCentralizedQueue, SlotDistributedQueues)
+from .queues import SlotCentralizedQueue, SlotDistributedQueues
 from .task import RangeTask
 from .victim import make_victim_selector
 
@@ -28,15 +26,7 @@ __all__ = ["SchedulerConfig", "ExecutionStats", "ScheduledExecutor"]
 
 @dataclass(frozen=True)
 class SchedulerConfig:
-    """User-facing scheduling knobs (the paper's two independent axes).
-
-    ``queue_impl`` selects the queue machinery behind the layout: "slot"
-    (preallocated slot-array queues on numpy index buffers, DESIGN.md §16)
-    or "deque" (the original lock-guarded deques, kept as the differential
-    reference). Both produce identical pop/steal sequences; this is a
-    pool/runtime property, so executors take it from the pool config even
-    for stages that override everything else.
-    """
+    """User-facing scheduling knobs (the paper's two independent axes)."""
 
     technique: str = "STATIC"         # work partitioning (11 options)
     queue_layout: str = "CENTRALIZED"  # CENTRALIZED | PERCORE | PERGROUP
@@ -44,12 +34,6 @@ class SchedulerConfig:
     n_workers: int = 4
     numa_domains: tuple[int, ...] | None = None  # one domain id per worker
     seed: int = 0
-    queue_impl: str = "slot"           # slot | deque (DESIGN.md §16)
-
-    def __post_init__(self):
-        if self.queue_impl not in QUEUE_IMPLS:
-            raise ValueError(
-                f"unknown queue_impl {self.queue_impl!r}; options: {QUEUE_IMPLS}")
 
 
 @dataclass
@@ -62,13 +46,11 @@ class ExecutionStats:
     steals: int = 0
     failed_steals: int = 0
     contended_pops: int = 0
-    # queue-access (lock round-trip) count: CentralizedQueue pops, or
+    # queue-access (lock round-trip) count: centralized-queue pops, or
     # pop_local + steal attempts under PERCORE/PERGROUP — the pop-traffic
     # axis on which queue layouts are compared.
     queue_pops: int = 0
-    # total measured queue wait (idle-to-next-task gaps summed over
-    # workers) — populated identically on the slot and deque impls so the
-    # differential tests can compare them.
+    # total measured queue wait (idle-to-next-task gaps summed over workers)
     queue_wait_s: float = 0.0
 
     @property
@@ -140,48 +122,28 @@ class ScheduledExecutor:
                                   t1 - t_start, 1 if stolen else 0, wait_s)
 
         t_start = time.perf_counter()
-        slot = cfg.queue_impl == "slot"
         if cfg.queue_layout.upper() == "CENTRALIZED":
-            if slot:
-                queue = SlotCentralizedQueue(tasks, cfg.technique,
-                                             cfg.n_workers, seed=cfg.seed)
+            queue = SlotCentralizedQueue(tasks, cfg.technique, cfg.n_workers,
+                                         seed=cfg.seed)
 
-                def worker(worker_id: int) -> None:
-                    """Drain chunk ranges off the slot-array queue."""
+            def worker(worker_id: int) -> None:
+                """Drain chunk ranges off the slot-array queue."""
+                t_idle = time.perf_counter()
+                while True:
+                    h, e = queue.pop_range(worker_id)
+                    if h == e:
+                        return
+                    wait = time.perf_counter() - t_idle
+                    for t in tasks[h:e]:
+                        record(worker_id, t, wait)
+                        wait = 0.0
                     t_idle = time.perf_counter()
-                    while True:
-                        h, e = queue.pop_range(worker_id)
-                        if h == e:
-                            return
-                        wait = time.perf_counter() - t_idle
-                        for t in tasks[h:e]:
-                            record(worker_id, t, wait)
-                            wait = 0.0
-                        t_idle = time.perf_counter()
-            else:
-                part = make_partitioner(cfg.technique, len(tasks),
-                                        cfg.n_workers, seed=cfg.seed)
-                queue = CentralizedQueue(tasks, part)
-
-                def worker(worker_id: int) -> None:
-                    """Drain technique-sized chunks off the shared queue."""
-                    t_idle = time.perf_counter()
-                    while True:
-                        chunk = queue.pop(worker_id)
-                        if not chunk:
-                            return
-                        wait = time.perf_counter() - t_idle
-                        for t in chunk:
-                            record(worker_id, t, wait)
-                            wait = 0.0
-                        t_idle = time.perf_counter()
 
             self._run_threads(worker, cfg.n_workers)
             stats.contended_pops = queue.contended_pops
             stats.queue_pops = queue.pops
         else:
-            cls = SlotDistributedQueues if slot else DistributedQueues
-            queues = cls(
+            queues = SlotDistributedQueues(
                 tasks, cfg.technique, cfg.n_workers,
                 layout=cfg.queue_layout, groups=self._domains, seed=cfg.seed,
             )
@@ -191,60 +153,33 @@ class ScheduledExecutor:
                               else list(range(queues.n_queues))),
                 seed=cfg.seed,
             )
-            if slot:
-                table = queues.task_table()
+            table = queues.task_table()
 
-                def worker(worker_id: int) -> None:
-                    """Drain the home queue in index space; steal by moving
-                    the victim's tail run into the home buffer (one int32
-                    copy, no task materialization on the queue op)."""
-                    home = queues.owner_of(worker_id)
-                    t_idle = time.perf_counter()
-                    just_stole = False
-                    while True:
-                        got = queues.pop_local_idx(worker_id)
-                        if len(got):
-                            wait = time.perf_counter() - t_idle
-                            for i in got:
-                                record(worker_id, table[i], wait, just_stole)
-                                wait = 0.0
-                            t_idle = time.perf_counter()
-                            just_stole = False
-                            continue
-                        moved = 0
-                        for victim in selector.candidates(home):
-                            moved = queues.steal_to_home(worker_id, victim)
-                            if moved:
-                                break
-                        if not moved:
-                            return  # global exhaustion
-                        just_stole = True
-            else:
-                def worker(worker_id: int) -> None:
-                    """Drain the home queue chunk-wise, then steal in victim order."""
-                    home = queues.owner_of(worker_id)
-                    t_idle = time.perf_counter()
-                    just_stole = False
-                    while True:
-                        chunk = queues.pop_local(worker_id)
-                        if chunk:
-                            wait = time.perf_counter() - t_idle
-                            for t in chunk:
-                                record(worker_id, t, wait, just_stole)
-                                wait = 0.0
-                            t_idle = time.perf_counter()
-                            just_stole = False
-                            continue
-                        # out of local work: steal (victim order per strategy)
-                        stolen: list[RangeTask] = []
-                        for victim in selector.candidates(home):
-                            stolen = queues.steal(worker_id, victim)
-                            if stolen:
-                                break
-                        if not stolen:
-                            return  # global exhaustion
-                        queues.push_local(worker_id, stolen)
-                        just_stole = True
+            def worker(worker_id: int) -> None:
+                """Drain the home queue in index space; steal by moving
+                the victim's tail run into the home buffer (one int32
+                copy, no task materialization on the queue op)."""
+                home = queues.owner_of(worker_id)
+                t_idle = time.perf_counter()
+                just_stole = False
+                while True:
+                    got = queues.pop_local_idx(worker_id)
+                    if len(got):
+                        wait = time.perf_counter() - t_idle
+                        for i in got:
+                            record(worker_id, table[i], wait, just_stole)
+                            wait = 0.0
+                        t_idle = time.perf_counter()
+                        just_stole = False
+                        continue
+                    moved = 0
+                    for victim in selector.candidates(home):
+                        moved = queues.steal_to_home(worker_id, victim)
+                        if moved:
+                            break
+                    if not moved:
+                        return  # global exhaustion
+                    just_stole = True
 
             self._run_threads(worker, cfg.n_workers)
             stats.steals = queues.steals

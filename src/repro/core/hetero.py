@@ -32,9 +32,9 @@ value depends only on the chunk boundaries — not on which substrate or
 thread ran each chunk, nor on absorption. Run at tile granularity
 (technique ``SS`` on a tile-unit DAG) this reproduces the host-only
 ``PipelineExecutor(technique="SS", n_workers=1)`` result bit-wise on the
-vee linreg/recommendation lowerings (CI-gated by
-``hetero_linreg_placement``). Concat stages write disjoint rows and are
-bit-equal under any placement/technique.
+vee linreg/recommendation lowerings (``tests/test_placement.py``).
+Concat stages write disjoint rows and are bit-equal under any
+placement/technique.
 """
 
 from __future__ import annotations

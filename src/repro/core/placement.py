@@ -30,8 +30,7 @@ accounted for moving rows across the boundary. This module is that layer:
                          {HOST, DEVICE, SPLIT(f)} accepting only
                          improvements — so the chosen placement's simulated
                          makespan is NEVER worse than min(host-only,
-                         device-only), the ``hetero_linreg_placement`` CI
-                         gate.
+                         device-only) (``tests/test_placement.py``).
 
 ``core/hetero.py`` executes a chosen placement for real (device super-table
 shards concurrently with host chunk workers); ``core/autotune.py`` wraps
@@ -169,7 +168,7 @@ class Placement:
         return cls({n: StagePlacement(DEVICE) for n in names})
 
     def describe(self) -> str:
-        """Compact one-line tag (for bench rows / logs)."""
+        """Compact one-line tag (for logs)."""
         parts = []
         for n, p in self.stages.items():
             if p.substrate == SPLIT:
@@ -511,8 +510,8 @@ def select_placement(
     then coordinate-descends per stage over {HOST, DEVICE, SPLIT(f) for f
     in ``fractions``} with ``simulate_hetero_dag`` as the objective,
     accepting only improvements. The returned placement's simulated
-    makespan is therefore NEVER worse than min(host-only, device-only) —
-    the ``hetero_linreg_placement`` CI gate — and strictly better whenever
+    makespan is therefore NEVER worse than min(host-only, device-only)
+    (``tests/test_placement.py``) and strictly better whenever
     stages have opposite substrate affinities (the transfer term keeps the
     solver from ping-ponging rows across the boundary to get there).
 

@@ -498,8 +498,8 @@ def migrate_to_device(ck: JobCheckpoint, lowering, tracer=None):
     granularity) and filtered to the pending tiles:
 
     * fully-completed stages are dropped from the walker and their
-      checkpointed values fed back as plain operands (the stagewise
-      baseline's producer-as-operand trick);
+      checkpointed values fed back as plain operands (the producer-as-operand
+      trick of ``dag_walk_stagewise``);
     * partially-done sum stages keep their pending slots and are seeded
       with the checkpoint's prefix accumulator at their first slot —
       added once under ``pl.when``, before the slot's own contribution,

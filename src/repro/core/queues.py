@@ -17,8 +17,9 @@ from the victim's queue tail.
 
 Two implementations of each layout (DESIGN.md §16):
 
-  ``deque``  the original lock-guarded ``collections.deque`` queues — kept
-             as the reference for differential testing.
+  ``deque``  the original lock-guarded ``collections.deque`` queues
+             (``CentralizedQueue``, ``DistributedQueues``) — kept as the
+             reference for differential testing.
   ``slot``   preallocated slot-array queues over numpy index buffers:
              tasks live in one shared table, each queue holds int32 task
              indices between a head and a tail cursor, and fill-time chunk
@@ -29,7 +30,7 @@ Two implementations of each layout (DESIGN.md §16):
              (technique, remaining, n_workers, seed).
 
 Both produce bit-identical pop/steal sequences (property-tested in
-tests/test_slot_queues.py); ``SchedulerConfig.queue_impl`` selects.
+tests/test_slot_queues.py); the executor drains the slot queues.
 """
 
 from __future__ import annotations
@@ -46,7 +47,7 @@ from .task import RangeTask
 
 __all__ = [
     "CentralizedQueue", "DistributedQueues", "SlotCentralizedQueue",
-    "SlotDistributedQueues", "QUEUE_LAYOUTS", "QUEUE_IMPLS",
+    "SlotDistributedQueues", "QUEUE_LAYOUTS",
 ]
 
 
@@ -699,4 +700,3 @@ class SlotDistributedQueues:
 
 
 QUEUE_LAYOUTS = ("CENTRALIZED", "PERCORE", "PERGROUP")
-QUEUE_IMPLS = ("slot", "deque")

@@ -499,7 +499,8 @@ def frozen_dag_makespans(
     barrier between launches (the pre-§11 device path) — each stage pays
     its own h_launch and waits for its slowest shard. Since
     max-of-sums <= sum-of-maxes and the fused path pays h_launch once,
-    fused <= sequential always (the ``device_dag_linreg`` CI gate).
+    fused <= sequential always (property-tested in
+    ``tests/test_device_dag.py``).
     """
     names = list(ddt.stage_names)
     ov = overheads

@@ -33,9 +33,9 @@ Supported edge reads: ``rows`` (elementwise dep on a ``concat`` producer
 dep on a ``sum`` producer — the whole accumulator; full deps on concat
 producers need a launch split, see build_dag_tables). ``dag_walk_stagewise``
 runs the same stages as one launch per stage (producer outputs re-fed as
-plain operands) — the baseline the fused walker is benchmarked against
-(``device_dag_linreg``); both paths execute identical per-tile ops in
-identical per-stage order, so their results match bit-wise.
+plain operands): the tests' reference for the fused walker, which it
+matches bit-wise on one backend (identical per-tile ops in identical
+per-stage order).
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ from .mode import pallas_call
 
 __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
            "dag_walk_stagewise", "dag_walk_sharded",
-           "device_table_cache_stats", "clear_device_table_cache",
+           "device_table_cache_stats",
            "walker_call_cache_stats", "clear_walker_call_cache",
            "walker_hold_stats"]
 
@@ -79,13 +79,6 @@ _DEVICE_TABLE_STATS = {"hits": 0, "misses": 0}
 def device_table_cache_stats() -> dict:
     """Device-table cache counters: ``{"hits", "misses", "size"}``."""
     return {**_DEVICE_TABLE_STATS, "size": len(_DEVICE_TABLE_CACHE)}
-
-
-def clear_device_table_cache() -> None:
-    """Drop device-resident tables and reset the hit/miss counters."""
-    _DEVICE_TABLE_CACHE.clear()
-    _DEVICE_TABLE_STATS["hits"] = 0
-    _DEVICE_TABLE_STATS["misses"] = 0
 
 
 # ---------------------------------------------------------------------------
@@ -533,13 +526,13 @@ def dag_walk_stagewise(
     table: np.ndarray,
     tile: int,
 ) -> dict[str, jax.Array]:
-    """One launch per stage: the pre-fusion baseline.
+    """One launch per stage: the tests' reference for the fused walker.
 
     Each stage drains only its own slots of the super-table; producer
     outputs from earlier launches are re-fed as plain operands. Identical
-    per-tile ops in identical per-stage order as the fused walker, so the
-    results match bit-wise — the fused path saves the launch boundaries,
-    not arithmetic.
+    per-tile ops in identical per-stage order as the fused walker, so on
+    one backend the results match bit-wise (``tests/test_cc_device.py``,
+    ``tests/test_walker_operand_hold.py``, ``tests/test_device_dag.py``).
     """
     table = np.asarray(table, dtype=np.int32)
     ops_by_name = {o.name: o for o in operands}

@@ -480,7 +480,6 @@ def run_device_dag(
     n_workers: int | None = None,
     chunk_costs: dict | None = None,
     seed: int = 0,
-    stagewise: bool = False,
     tracer=None,
     job: str = "",
 ):
@@ -488,9 +487,7 @@ def run_device_dag(
 
     Freezes the tile-unit DAG with ``build_dag_tables_cached`` (per-stage
     techniques), scales the super-table slots to row space, then drains
-    them with the fused multi-stage walker — or one launch per stage
-    when ``stagewise=True`` (the pre-fusion baseline the
-    ``device_dag_linreg`` bench row compares against). Returns
+    them with the fused multi-stage walker. Returns
     ``(values, tables)``: stage outputs as numpy arrays (row space) and
     the DeviceDagTables (tile units) actually walked.
 
@@ -503,7 +500,7 @@ def run_device_dag(
     A lowering with a ``fixpoint`` walks once per round, each round on
     the job's one table (cached device table, memoized walker) with the
     previous round's outputs fed into its operands, and returns the last
-    round's values (``Fixpoint``); the stagewise baseline walks once.
+    round's values (``Fixpoint``).
 
     The call is the ``devdag.job`` phase (DESIGN.md §18) of ``tracer``
     under ``job``; inside it ``devdag.tables`` (signature, table build,
@@ -515,13 +512,11 @@ def run_device_dag(
     """
     from ..core.device_schedule import build_dag_tables_cached, dag_signature
     from ..core.telemetry import as_tracer, phase, watch_compiles
-    from ..kernels.dag_walk import dag_walk_sharded, dag_walk_stagewise
+    from ..kernels.dag_walk import dag_walk_sharded
 
     tracer = as_tracer(tracer)
     if tracer.enabled:
         watch_compiles()
-    if stagewise and (n_shards != 1 or lowering.fixpoint is not None):
-        raise ValueError("stagewise baseline runs single-shard, one walk")
     with phase(tracer, "devdag.job", job):
         with phase(tracer, "devdag.tables", job):
             key = dag_signature(
@@ -534,17 +529,13 @@ def run_device_dag(
             rows[:, :, 1:] *= lowering.tile  # tile units -> row space
 
         def walk(values):
-            if stagewise:
-                out = dag_walk_stagewise(lowering.stages, lowering.operands,
-                                         values, rows[0], lowering.tile)
-            else:
-                # an untraced call keeps the walker's plain signature, so a
-                # wrapper of dag_walk_sharded (fault injection) sees no change
-                traced = {"tracer": tracer, "job": job} if tracer.enabled else {}
-                out = dag_walk_sharded(lowering.stages, lowering.operands,
-                                       values, rows, lowering.tile,
-                                       table_key=("devdag", lowering.tile, key),
-                                       **traced)
+            # an untraced call keeps the walker's plain signature, so a
+            # wrapper of dag_walk_sharded (fault injection) sees no change
+            traced = {"tracer": tracer, "job": job} if tracer.enabled else {}
+            out = dag_walk_sharded(lowering.stages, lowering.operands,
+                                   values, rows, lowering.tile,
+                                   table_key=("devdag", lowering.tile, key),
+                                   **traced)
             return {k: np.asarray(v) for k, v in out.items()}
 
         if lowering.fixpoint is None:
@@ -943,16 +934,14 @@ def linear_regression_device(
     stage_techniques: dict | str | None = None,
     lam: float = 0.001,
     seed: int = 1,
-    stagewise: bool = False,
 ):
     """Paper Listing 2 end-to-end on the device-DAG path.
 
-    Returns (beta, stage values, DeviceDagTables). ``stagewise=True``
-    runs the one-launch-per-stage baseline instead of the fused walker.
+    Returns (beta, stage values, DeviceDagTables).
     """
     low = linreg_device_lowering(num_rows, num_cols, tile=tile, lam=lam,
                                  seed=seed)
-    vals, ddt = run_device_dag(low, stage_techniques, stagewise=stagewise)
+    vals, ddt = run_device_dag(low, stage_techniques)
     return low.finalize(vals), vals, ddt
 
 
@@ -1030,7 +1019,6 @@ def recommendation_device(
     stage_techniques: dict | str | None = None,
     density: float = 0.3,
     seed: int = 0,
-    stagewise: bool = False,
 ):
     """The recommendation pipeline end-to-end on the device-DAG path.
 
@@ -1039,7 +1027,7 @@ def recommendation_device(
     """
     low = recommendation_device_lowering(n_users, n_items, tile=tile,
                                          density=density, seed=seed)
-    vals, ddt = run_device_dag(low, stage_techniques, stagewise=stagewise)
+    vals, ddt = run_device_dag(low, stage_techniques)
     return vals["scores"].reshape(-1), vals, ddt
 
 
@@ -1056,8 +1044,7 @@ def hetero_affinity_dag(n: int = 4096):
     consumes both elementwise. The transfer term is priced so that
     ping-ponging rows across the boundary is expensive: the solver must
     keep each branch substrate-resident and overlap them to win. ONE
-    definition serves the ``hetero_linreg_placement`` CI gate
-    (``benchmarks/run.py``), ``examples/hetero_pipeline.py``, and
+    definition serves ``examples/hetero_pipeline.py`` and
     ``tests/test_placement.py`` so they cannot drift apart. Returns
     ``(dag, HeteroCostModel)``; the ops are placeholders (virtual-time
     replays never execute stage bodies).

@@ -241,7 +241,8 @@ def test_replay_open_loop_percentiles_and_accounting():
 
 
 def test_front_door_beats_fifo_baseline_on_overload():
-    """The pipeline_server_openloop gate as a tier-1 property."""
+    """Admission and batching beat no-admission FIFO on p99.9 latency and
+    deadline hit-rate on an overloaded open-loop trace."""
     trace = heavy_tailed_trace(400, seed=3, load=1.5, n_workers=8)
     base = replay_open_loop(trace, n_workers=8, arbiter="fifo")
     fb = FeedbackLog()

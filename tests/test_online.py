@@ -134,8 +134,8 @@ def test_replay_deterministic(selector):
 
 
 def test_tune_online_dag_multi_stage_near_offline():
-    """The autotune entry point: online lands within the CI gate's 1.10x
-    of the offline per-stage search on the linreg-shaped workload."""
+    """The autotune entry point: online lands within 1.10x of the offline
+    per-stage search on the linreg-shaped workload."""
     from repro.core import select_offline_dag
 
     n = 1024

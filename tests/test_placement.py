@@ -53,7 +53,7 @@ def _op(inputs, s, z):
 
 def _affinity_dag(n=2048):
     """ingest -> (featurize | embed) -> join with opposite affinities
-    (the shared §13 demo workload — also the CI gate's and the example's)."""
+    (the shared §13 demo workload — also the example's)."""
     from repro.vee.apps import hetero_affinity_dag
 
     return hetero_affinity_dag(n)
@@ -81,7 +81,7 @@ def test_solver_never_worse_than_homogeneous_and_mixed_wins():
     placement, ms, base = select_placement(dag, costs, n_workers=8, passes=2)
     assert ms <= min(base.values()) + 1e-12
     # opposite affinities + transfer awareness: the mixed placement must
-    # STRICTLY beat both homogeneous runs (the hetero_linreg_placement gate)
+    # STRICTLY beat both homogeneous runs
     assert ms < base["host"]
     assert ms < base["device"]
     subs = {p.substrate for p in placement.stages.values()}

@@ -96,17 +96,15 @@ def _values_equal(got, want):
     p_workers=st.integers(1, 4),
     tech=st.sampled_from(TECHS),
     layout=st.sampled_from(LAYOUTS),
-    impl=st.sampled_from(["slot", "deque"]),
     shape=st.sampled_from(["chain2", "chain3", "diamond"]),
     kind=st.sampled_from(["full", "elementwise"]),
     cut=st.integers(0, 60),
 )
 def test_exactly_once_under_random_preemption(n, p_workers, tech, layout,
-                                              impl, shape, kind, cut):
+                                              shape, kind, cut):
     dag = _int_dag(n, shape, kind)
     cfg = SchedulerConfig(technique=tech, queue_layout=layout,
-                          victim_strategy="RND", n_workers=p_workers, seed=0,
-                          queue_impl=impl)
+                          victim_strategy="RND", n_workers=p_workers, seed=0)
     ref = PipelineExecutor(dag, cfg).run()
     res, ck = PreemptiveRunner(dag, cfg, preempt_after=max(1, cut)).run()
     if ck is None:

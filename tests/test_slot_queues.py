@@ -1,10 +1,10 @@
 """Differential tests: slot-array queues vs the deque reference (§16).
 
-The slot-array implementation must be indistinguishable from the original
-lock-guarded deques behind the public queue API: identical pop/steal chunk
-sequences under identical op sequences, identical counters, exactly-once
-task delivery, and bit-equal executor results under both
-``SchedulerConfig.queue_impl`` settings. The steal-amount memoization rests
+The slot-array implementation the executor drains must be
+indistinguishable from the original lock-guarded deques behind the public
+queue API: identical pop/steal chunk sequences under identical op
+sequences, identical counters and exactly-once task delivery. The
+steal-amount memoization rests
 on ``first_chunk`` / ``first_chunk_fn`` reproducing a fresh partitioner's
 first chunk, so that equivalence is property-tested against the real
 partitioners here too.
@@ -18,8 +18,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import (PARTITIONERS, CentralizedQueue, DistributedQueues,
-                        RangeTask, ScheduledExecutor, SchedulerConfig,
-                        SlotCentralizedQueue, SlotDistributedQueues,
+                        RangeTask, SlotCentralizedQueue, SlotDistributedQueues,
                         first_chunk, first_chunk_fn, make_partitioner)
 
 TECHS = sorted(PARTITIONERS)
@@ -176,44 +175,6 @@ def test_steal_to_home_matches_steal_plus_push(tech, n, p, seed):
                 break
     assert a.steals == b.steals
     assert a.queue_sizes() == b.queue_sizes()
-
-
-# ---------------------------------------------------------------------------
-# executor level: bit-equal results under either queue_impl
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("layout", ["CENTRALIZED", "PERCORE", "PERGROUP"])
-@pytest.mark.parametrize("tech", ["SS", "GSS", "MFSC"])
-def test_executor_results_equal_across_impls(layout, tech):
-    n, p = 257, 4
-    x = np.arange(n, dtype=np.float64)
-
-    def run(impl):
-        tasks = [RangeTask(i, i, 1, lambda s, z: float(x[s:s + z].sum()), 1.0)
-                 for i in range(n)]
-        cfg = SchedulerConfig(technique=tech, queue_layout=layout,
-                              n_workers=p, queue_impl=impl,
-                              numa_domains=(0, 0, 1, 1))
-        return ScheduledExecutor(cfg).run(tasks)
-
-    res_s, st_s = run("slot")
-    res_d, st_d = run("deque")
-    assert res_s == res_d  # exactly-once, bit-equal values
-    if layout == "CENTRALIZED":
-        # chunk count is frozen at fill/pop time and every worker pays one
-        # terminating empty pop: the counter is deterministic across impls
-        assert st_s.queue_pops == st_d.queue_pops
-    else:
-        # steal interleaving is thread-timing dependent, but the counter
-        # definition (pops + steals + failed steals) holds for both
-        assert st_s.queue_pops > 0 and st_d.queue_pops > 0
-        assert st_s.steals + st_s.failed_steals <= st_s.queue_pops
-        assert st_d.steals + st_d.failed_steals <= st_d.queue_pops
-
-
-def test_unknown_queue_impl_rejected():
-    with pytest.raises(ValueError, match="queue_impl"):
-        SchedulerConfig(queue_impl="ring")
 
 
 # ---------------------------------------------------------------------------

@@ -61,7 +61,6 @@ from repro.core.telemetry import DEVDAG_PHASES, F_DEVICE, WORK_KINDS
 
 TECHS = ["STATIC", "SS", "MFSC", "GSS", "FAC2", "TSS"]
 LAYOUTS = ["CENTRALIZED", "PERCORE", "PERGROUP"]
-IMPLS = ["deque", "slot"]
 EPS = 1e-9
 
 
@@ -121,17 +120,23 @@ def _check_invariants(tracer, events):
 @given(n=st.integers(8, 48), n_stages=st.integers(2, 4),
        full_a=st.booleans(), full_b=st.booleans(), full_c=st.booleans(),
        tech=st.sampled_from(TECHS), layout=st.sampled_from(LAYOUTS),
-       impl=st.sampled_from(IMPLS), workers=st.integers(1, 4))
+       workers=st.integers(1, 4))
 def test_span_invariants_host_pool(n, n_stages, full_a, full_b, full_c,
-                                   tech, layout, impl, workers):
+                                   tech, layout, workers):
     dag = _chain_dag(n, n_stages, [full_a, full_b, full_c])
     cfg = SchedulerConfig(technique=tech, queue_layout=layout,
-                          n_workers=workers, queue_impl=impl)
+                          n_workers=workers)
     tracer = Tracer(job="prop")
     res = PipelineExecutor(dag, cfg, tracer=tracer).run()
     execs = _check_invariants(tracer, list(res.events))
     assert all(s.job == "prop" for s in execs)
     assert validate_chrome_trace(tracer.to_chrome_trace()) == []
+    # tracing observes the run and never changes what it computes
+    plain = PipelineExecutor(dag, cfg).run()
+    assert set(plain.values) == set(res.values)
+    for k in plain.values:
+        assert np.array_equal(np.asarray(res.values[k]),
+                              np.asarray(plain.values[k])), k
 
 
 @settings(max_examples=10, deadline=None)
@@ -228,48 +233,27 @@ def test_critical_path_empty_and_synthetic():
 
 
 # ---------------------------------------------------------------------------
-# slot-vs-deque queue-wait differential (the wait_s reconciliation fix)
+# queue-wait accounting
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_queue_wait_populated_per_impl(impl):
+def test_queue_wait_populated():
     dag = _chain_dag(256, 2, [False])
     cfg = SchedulerConfig(technique="SS", queue_layout="CENTRALIZED",
-                          n_workers=4, queue_impl=impl)
+                          n_workers=4)
     res = PipelineExecutor(dag, cfg).run()
     waits = [e.wait_s for e in res.events]
     assert all(w >= 0.0 for w in waits)
     assert any(w > 0.0 for w in waits), (
-        f"{impl}: no queue wait measured across {len(waits)} chunks")
+        f"no queue wait measured across {len(waits)} chunks")
     # DagResult.stats folds the same numbers — no reconciliation gap
     st_ = res.stats
     assert st_.total_queue_wait_s == pytest.approx(sum(waits), rel=1e-9)
 
 
-def test_slot_vs_deque_differential_stats():
-    """The slot path used to drop queue waits entirely; both impls must
-    now produce the same chunk accounting (the schedule is deterministic)
-    with wait_s populated and internally consistent."""
-    dag = _chain_dag(96, 3, [False, False])
-    per = {}
-    for impl in IMPLS:
-        cfg = SchedulerConfig(technique="GSS", queue_layout="PERCORE",
-                              n_workers=4, queue_impl=impl)
-        res = PipelineExecutor(dag, cfg).run()
-        st_ = res.stats
-        assert st_.total_queue_wait_s > 0.0, f"{impl}: waits not populated"
-        assert st_.total_queue_wait_s == pytest.approx(
-            sum(e.wait_s for e in res.events), rel=1e-9)
-        per[impl] = st_
-    # same technique -> same chunk plan, whichever queue holds it
-    assert per["deque"].chunks == per["slot"].chunks
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-def test_scheduled_executor_queue_wait_stat(impl):
+def test_scheduled_executor_queue_wait_stat():
     from repro.core import ScheduledExecutor, tasks_from_schedule
     cfg = SchedulerConfig(technique="SS", queue_layout="CENTRALIZED",
-                          n_workers=4, queue_impl=impl)
+                          n_workers=4)
     tasks = tasks_from_schedule([(i, 1) for i in range(0, 128)],
                                 lambda s, z: float(s))
     _, st_ = ScheduledExecutor(cfg).run(tasks)

@@ -8,8 +8,9 @@ different schedule with the same slot count reuses the compiled walker
 and still computes its own answer. The lowerings build their stage bodies
 structurally, so a job of the same shape with new data hits, and merged
 §14 batches of the same members compare equal stage by stage, so they
-share one entry. Everything here runs on the CPU, in Pallas's TPU
-interpret mode.
+share one entry. Jobs of one shape through ``run_device_dag`` also hit
+the host lowering memo and the device-resident table cache after the
+first. Everything here runs on the CPU, in Pallas's TPU interpret mode.
 """
 
 import dataclasses
@@ -17,7 +18,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import compile_counts
+from repro.core import compile_counts, dag_table_cache_stats
 from repro.core.device_schedule import build_dag_tables_cached
 from repro.core.telemetry import watch_compiles
 from repro.kernels import dag_walk as dw
@@ -193,23 +194,48 @@ def _recommendation_pair():
         for s in (0, 1)]
 
 
-@pytest.mark.parametrize("pair", [_linreg_pair, _recommendation_pair],
-                         ids=["linreg", "recommendation"])
-def test_new_data_same_shape_hits(pair):
+def _host_caches():
+    """Hits and misses of the lowering memo and the device table cache."""
+    return [(s["hits"], s["misses"])
+            for s in (dag_table_cache_stats(), dw.device_table_cache_stats())]
+
+
+@pytest.mark.parametrize("pair, through", [
+    pytest.param(_linreg_pair, "walker", id="linreg"),
+    pytest.param(_recommendation_pair, "walker", id="recommendation"),
+    pytest.param(_linreg_pair, "job", id="linreg-job"),
+])
+def test_new_data_same_shape_hits(pair, through):
     """Jobs of one shape with new data share one compiled walker.
 
     Each lowering builds its own operands, closures and host ops, but its
     stage bodies are module-level functions or frozen dataclasses of their
     constants, so the stages compare equal and the second job hits; each
-    job still computes its own answer.
+    job still computes its own answer. Through ``run_device_dag`` the
+    second job also finds its lowering and its device-resident table
+    cached, and its answer equals an uncached walk of the same table.
     """
     lows, checks = pair()
     assert lows[0].stages == lows[1].stages
     assert not np.array_equal(np.asarray(next(iter(lows[0].values.values()))),
                               np.asarray(next(iter(lows[1].values.values()))))
-    for low, check in zip(lows, checks):
-        check(_walk(low, _rows_table(low)))
+    if through == "walker":
+        for low, check in zip(lows, checks):
+            check(_walk(low, _rows_table(low)))
+        assert _stats() == (1, 1, 1)
+        return
+    outs = [run_device_dag(lows[0], "GSS")[0]]
+    before = _host_caches()
+    outs.append(run_device_dag(lows[1], "GSS")[0])
+    after = _host_caches()
     assert _stats() == (1, 1, 1)
+    for (h0, m0), (h1, m1) in zip(before, after):
+        assert (h1 - h0, m1 - m0) == (1, 0)
+    for low, check, out in zip(lows, checks, outs):
+        check(out)
+        cold = _walk(low, _rows_table(low))   # table put anew, no key
+        for k in out:
+            assert np.array_equal(out[k], cold[k]), k
 
 
 def test_moe_lowerings_of_one_shape_compare_equal():

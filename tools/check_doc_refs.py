@@ -1,7 +1,7 @@
 """Docs gate: every code path referenced in README.md / DESIGN.md must exist.
 
 Scans backtick-quoted path-like references (``core/dag.py``,
-``benchmarks/run.py``, ``src/repro/...``; a trailing ``:symbol`` or
+``bench/run.py``, ``src/repro/...``; a trailing ``:symbol`` or
 anchor is ignored) and resolves each against the repo root, ``src/``,
 and ``src/repro/``. Exits non-zero listing any reference that resolves
 nowhere — so renames/moves can't silently rot the docs. Also scans
