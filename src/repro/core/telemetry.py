@@ -568,13 +568,16 @@ def collect_queue_metrics(reg: MetricsRegistry, counters: dict,
 
 def collect_cache_metrics(reg: MetricsRegistry) -> None:
     """Lowering-memo, device-resident table and walker call hit rates (§16),
-    the walker's held operand steps (``walker_hold_stats``, §11) and the
-    fixed-point loop's jobs and launches (``loop_counts``)."""
+    the walker's held operand steps (``walker_hold_stats``, §11), its
+    concat outputs taken as written or merged by row ownership
+    (``walker_combine_stats``, §11) and the fixed-point loop's jobs and
+    launches (``loop_counts``)."""
     from .device_schedule import dag_table_cache_stats
     pairs = [("lowering_cache", dag_table_cache_stats())]
     try:
         from ..kernels.dag_walk import (device_table_cache_stats,
                                         walker_call_cache_stats,
+                                        walker_combine_stats,
                                         walker_hold_stats)
         pairs.append(("device_table_cache", device_table_cache_stats()))
         pairs.append(("walker_call_cache", walker_call_cache_stats()))
@@ -585,6 +588,13 @@ def collect_cache_metrics(reg: MetricsRegistry) -> None:
         reg.counter("sched_walker_inner_steps",
                     "walker grid steps x inner-indexed operands"
                     ).inc(hold["inner_steps"])
+        combine = walker_combine_stats()
+        reg.counter("sched_walker_combine_taken",
+                    "concat outputs returned as the walker wrote them"
+                    ).inc(combine["taken"])
+        reg.counter("sched_walker_combine_merged",
+                    "concat outputs merged by row ownership"
+                    ).inc(combine["merged"])
     except Exception:  # pragma: no cover - kernels unavailable
         pass
     for name, st in pairs:

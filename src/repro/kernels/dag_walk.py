@@ -58,7 +58,7 @@ __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
            "dag_walk_stagewise", "dag_walk_sharded",
            "device_table_cache_stats",
            "walker_call_cache_stats", "clear_walker_call_cache",
-           "walker_hold_stats"]
+           "walker_hold_stats", "walker_combine_stats"]
 
 
 # ---------------------------------------------------------------------------
@@ -72,7 +72,7 @@ __all__ = ["WalkOperand", "WalkStage", "WalkCtx", "walk_call", "dag_walk",
 # caller reuses a key for a rebalanced table.
 # ---------------------------------------------------------------------------
 
-_DEVICE_TABLE_CACHE: dict[tuple, tuple[jax.Array, np.ndarray]] = {}
+_DEVICE_TABLE_CACHE: dict[tuple, tuple[jax.Array, np.ndarray, tuple]] = {}
 _DEVICE_TABLE_STATS = {"hits": 0, "misses": 0}
 
 
@@ -162,6 +162,48 @@ def _count_held_steps(plan: tuple, live: np.ndarray, n_slots: int) -> None:
                                             for k, inner in held)
 
 
+# ---------------------------------------------------------------------------
+# concat combine (DESIGN.md §11)
+#
+# A shard's walker output holds the rows of a concat stage that the
+# shard's live slots own; other rows hold whatever the device left there.
+# ``dag_walk_sharded`` merges them by the rows each table owns
+# (``_row_spans``, counted once per keyed table): with one shard that owns
+# every row, the walker's output is the value as it is (``taken``); else a
+# zeroed buffer takes each shard's owned rows in ascending shard order
+# (``merged``).
+# ---------------------------------------------------------------------------
+
+_WALKER_COMBINE_STATS = {"taken": 0, "merged": 0}
+
+
+def walker_combine_stats() -> dict:
+    """Concat combine counters: ``{"taken", "merged"}``."""
+    return dict(_WALKER_COMBINE_STATS)
+
+
+def _combine_concat(shape: tuple[int, ...], outs: list[np.ndarray],
+                    spans: list[tuple[np.ndarray, np.ndarray]]) -> np.ndarray:
+    """One concat stage's value from its shards' host outputs ``outs`` and
+    the rows each shard's slots own ``spans``: the rows no slot owns are
+    zero, and a later shard wins a row two shards own."""
+    n_rows = shape[0]
+    starts, ends = spans[0]
+    if len(outs) == 1 and len(starts) and starts[0] == 0 and ends[0] >= n_rows:
+        _WALKER_COMBINE_STATS["taken"] += 1
+        return outs[0]
+    _WALKER_COMBINE_STATS["merged"] += 1
+    buf = np.zeros(shape, outs[0].dtype)
+    for out, (starts, ends) in zip(outs, spans):
+        keep = starts < n_rows
+        edges = np.zeros(n_rows + 1, np.int8)
+        edges[starts[keep]] = 1
+        edges[np.minimum(ends[keep], n_rows)] = -1
+        owned = np.cumsum(edges[:n_rows]) > 0
+        np.copyto(buf, out, where=owned.reshape((-1,) + (1,) * (buf.ndim - 1)))
+    return buf
+
+
 def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
     """The walker's prefetched table: ``(n_slots, 3 + n_stages) int32``.
 
@@ -196,10 +238,35 @@ def _walk_table(table: np.ndarray, stages: list[WalkStage]) -> np.ndarray:
     return np.ascontiguousarray(np.concatenate([table, cur], axis=1))
 
 
+def _row_spans(table: np.ndarray, n_stages: int
+               ) -> tuple[tuple[np.ndarray, np.ndarray], ...]:
+    """Per stage, the rows its live slots own: ascending ``(starts, ends)``
+    of disjoint runs, overlapping and touching slots merged."""
+    live = table[:, 2] > 0
+    stage = table[live, 0]
+    start = table[live, 1].astype(np.int64)
+    end = start + table[live, 2]
+    order = np.lexsort((start, stage))
+    stage, start, end = stage[order], start[order], end[order]
+    bounds = np.searchsorted(stage, np.arange(n_stages + 1))
+    spans = []
+    for lo, hi in zip(bounds[:-1], bounds[1:]):
+        s, e = start[lo:hi], end[lo:hi]
+        if not len(s):
+            spans.append((s, e))
+            continue
+        reach = np.maximum.accumulate(e)
+        head = np.flatnonzero(np.r_[True, s[1:] > reach[:-1]])
+        spans.append((s[head], reach[np.r_[head[1:], len(s)] - 1]))
+    return tuple(spans)
+
+
 def _device_table(table: np.ndarray, key: tuple | None
-                  ) -> tuple[jax.Array, np.ndarray]:
+                  ) -> tuple[jax.Array, np.ndarray, tuple]:
     """Device-resident copy of a walker table (``_walk_table``), flattened,
-    and the table's live slot count per stage (for ``walker_hold_stats``).
+    the table's live slot count per stage (for ``walker_hold_stats``) and
+    the rows each stage's live slots own (``_row_spans``, for the concat
+    combine in ``dag_walk_sharded``).
 
     Unkeyed: a plain ``jax.device_put`` — async dispatch, so issuing it
     for shard ``s+1`` before walking shard ``s`` double-buffers the
@@ -208,15 +275,16 @@ def _device_table(table: np.ndarray, key: tuple | None
     handoff — the walker reads the cached buffer directly). The host
     array is never mutated afterwards (build_dag_tables_cached marks it
     read-only), so ``may_alias`` lets same-device backends alias the
-    host buffer instead of copying. The live counts are counted with the
-    put, so a keyed table counts them once.
+    host buffer instead of copying. The live counts and owned rows are
+    counted with the put, so a keyed table counts them once.
     """
     flat = table.reshape(-1)
 
     def put():
-        live = np.bincount(table[:, 0][table[:, 2] > 0],
-                           minlength=table.shape[1] - 3)
-        return jax.device_put(flat, may_alias=True), live
+        n_stages = table.shape[1] - 3
+        live = np.bincount(table[:, 0][table[:, 2] > 0], minlength=n_stages)
+        return (jax.device_put(flat, may_alias=True), live,
+                _row_spans(table, n_stages))
 
     if key is None:
         return put()
@@ -466,7 +534,7 @@ def dag_walk(
     table: np.ndarray,
     tile: int,
     table_key: tuple | None = None,
-    _dev_table: tuple[jax.Array, np.ndarray] | None = None,
+    _dev_table: tuple[jax.Array, np.ndarray, tuple] | None = None,
     tracer: Tracer | None = None,
     job: str = "",
     shard: int = 0,
@@ -507,7 +575,7 @@ def dag_walk(
     if _dev_table is None:
         with phase(tracer, "devdag.table_put", job, tag):
             _dev_table = _device_table(_walk_table(table, stages), table_key)
-    tbl_dev, live = _dev_table
+    tbl_dev, live, _ = _dev_table
     with phase(tracer, "devdag.launch", job, tag):
         call, plan = _jitted_walker(
             tuple(stages), tuple(operands),
@@ -566,9 +634,15 @@ def dag_walk_sharded(
     """Walk every shard's super-table and combine the per-shard outputs.
 
     ``tables`` is ``(n_shards, max_slots, 3)``. concat outputs merge by
-    tile ownership; sum outputs add per-shard partials (ascending shard
-    order — deterministic, but a different association than one shard, so
-    bit-wise claims hold per shard count).
+    row ownership, counted once per keyed table (``_row_spans``): with one
+    shard whose live slots own every row, the value is the shard's host
+    copy as the walker wrote it, like a one-shard sum value (and may be
+    read-only); otherwise a zeroed buffer takes each shard's owned rows in
+    ascending shard order, so rows no slot owns stay zero
+    (``walker_combine_stats`` counts both cases). sum outputs add
+    per-shard partials (ascending shard order — deterministic, but a
+    different association than one shard, so bit-wise claims hold per
+    shard count).
 
     Shard transfers are double-buffered: shard ``s+1``'s table is
     ``device_put`` (async dispatch) before shard ``s``'s launch, so the
@@ -594,9 +668,10 @@ def dag_walk_sharded(
             return _device_table(_walk_table(tables[s], stages), key(s))
 
     nxt = put(0) if n_shards else None
-    shard_outs = []
+    shard_outs, owned = [], []
     for s in range(n_shards):
         cur, nxt = nxt, (put(s + 1) if s + 1 < n_shards else None)
+        owned.append(cur[2])
         shard_outs.append(dag_walk(stages, operands, values, tables[s], tile,
                                    _dev_table=cur, tracer=tracer, job=job,
                                    shard=s))
@@ -613,11 +688,7 @@ def dag_walk_sharded(
                     acc = acc + h[s.name]
                 combined[s.name] = acc
             else:
-                buf = np.zeros(tuple(s.out_shape), host[0][s.name].dtype)
-                for sh in range(n_shards):
-                    out = host[sh][s.name]
-                    for sid, start, size in tables[sh]:
-                        if sid == k and size > 0:
-                            buf[start:start + size] = out[start:start + size]
-                combined[s.name] = buf
+                combined[s.name] = _combine_concat(
+                    tuple(s.out_shape), [h[s.name] for h in host],
+                    [o[k] for o in owned])
     return combined

@@ -633,6 +633,31 @@ def test_cache_metrics_export_walker_hold_share():
     assert held / inner == 0.5
 
 
+def test_cache_metrics_export_walker_combine_counts():
+    """A concat output walked by one shard is taken as the walker wrote it,
+    one walked by two is merged by row ownership; sum outputs count in
+    neither."""
+    from repro.core.telemetry import collect_cache_metrics
+    from repro.kernels.dag_walk import walker_combine_stats
+    from repro.vee.apps import linreg_device_lowering, run_device_dag
+
+    def exported():
+        reg = MetricsRegistry()
+        collect_cache_metrics(reg)
+        counters = reg.snapshot()["counters"]
+        return (counters["sched_walker_combine_taken"],
+                counters["sched_walker_combine_merged"])
+
+    start = exported()
+    assert start == tuple(walker_combine_stats().values())
+    run_device_dag(linreg_device_lowering(256, 5, tile=32), "GSS")
+    assert exported() == start
+    run_device_dag(_two_stage_lowering(), "STATIC")
+    assert exported() == (start[0] + 1, start[1])
+    run_device_dag(_two_stage_lowering(), "STATIC", n_shards=2, n_workers=2)
+    assert exported() == (start[0] + 1, start[1] + 1)
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
