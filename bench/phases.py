@@ -116,7 +116,9 @@ def make_traced_job(pipeline, lowerings: list, techniques, tracer_box: list):
     """``bench.run.make_job`` with the program's tracer (``tracer_box[0]``, or None) and job ids.
 
     Also returns the list it appends to per launch: ``(job id, seconds in
-    run_device_dag, backend compiles during the call)``.
+    run_device_dag, backend compiles during the call)``. It is the default
+    job only: a pipeline that brings its own ``make_job`` takes no tracer
+    from here, and ``run`` refuses it.
     """
     from jax.profiler import TraceAnnotation
 
@@ -144,8 +146,8 @@ def make_traced_job(pipeline, lowerings: list, techniques, tracer_box: list):
                       compile_counts()["backend_compiles"] - backend))
         with TraceAnnotation("bench.answer"):
             parts = [values] if n == 1 else apps.split_device_values(values, n)
-            return [(pipeline.answer(one, vals), time.perf_counter())
-                    for one, vals in zip(lowerings, parts)]
+            return [((k, 0), pipeline.answer(one, vals), time.perf_counter())
+                    for k, (one, vals) in enumerate(zip(lowerings, parts))]
 
     return job, calls
 
@@ -181,7 +183,11 @@ def run(args) -> dict:
     jax.monitoring.register_event_duration_secs_listener(count_compile)
 
     pipeline = harness.pipeline_of(cfg)
+    if hasattr(pipeline, "make_job"):
+        raise SystemExit(f"phases: pipeline {cfg['pipeline']!r} brings its own make_job, "
+                         "to which make_traced_job cannot pass the program's tracer")
     rows, seeds = harness.tenants_of(cfg, traffic, args.seed)
+    owed = harness.owed_of(pipeline, cfg, traffic)
     lowerings = [pipeline.build(cfg, rows, s) for s in seeds]
     tracer_box = [None]
     job, calls = make_traced_job(pipeline, lowerings, cfg["techniques"], tracer_box)
@@ -200,7 +206,7 @@ def run(args) -> dict:
         for mode in ("off", "on") if r % 2 == 0 else ("on", "off"):
             tracer_box[0] = tracer if mode == "on" else None
             first, before = len(calls), compile_counts()
-            win = harness.run_window(job, args.seconds, compiles)
+            win = harness.run_window(job, args.seconds, compiles, owed)
             after = compile_counts()  # watching from the first on job's start
             win["calls"] = calls[first:]
             windows[mode].append(win)
@@ -239,7 +245,7 @@ def run(args) -> dict:
     trace_dir = tempfile.mkdtemp(prefix="phases-trace-")
     tracer_box[0] = Tracer()
     harness.start_trace(trace_dir)
-    traced = harness.run_window(job, min(args.seconds, harness.TRACE_SECONDS), compiles)
+    traced = harness.run_window(job, min(args.seconds, harness.TRACE_SECONDS), compiles, owed)
     jax.profiler.stop_trace()
 
     from bench import trace

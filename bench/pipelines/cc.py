@@ -41,7 +41,8 @@ class Checker:
         src, dst = ref.kronecker(cfg["scale"], cfg["edge_factor"], cfg["graph_seed"])
         self.want = ref.components(rows, src, dst)
 
-    def __call__(self, got) -> dict:
+    def __call__(self, got, request: int = 0) -> dict:
+        """The numbers ``got`` is judged by; a job answers one request, 0."""
         return ref.compare(got, self.want)
 
 
@@ -50,8 +51,8 @@ def control(cfg: dict, rows: int, seed: int):
     return ref.control(cfg["scale"], cfg["edge_factor"], cfg["graph_seed"])
 
 
-def work(cfg: dict, rows: int) -> dict:
-    """The least work of one job: read the stored adjacency once, write n labels.
+def work(cfg: dict, rows: int, traffic: dict) -> dict:
+    """The least work of one job, its one answer: read the stored adjacency once, write n labels.
 
     The adjacency is ``rows x rows`` float32. A one-pass method
     (union-find over the stored graph) needs no more than one read, so

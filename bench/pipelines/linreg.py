@@ -34,7 +34,8 @@ class Checker:
         gram = ref.normal_equations(rows, cfg["cols"], seed)
         self.want = {"beta": ref.solve(gram, cfg["lam"])}
 
-    def __call__(self, got: dict) -> dict:
+    def __call__(self, got: dict, request: int = 0) -> dict:
+        """The numbers ``got`` is judged by; a job answers one request, 0."""
         return ref.compare(got, self.want)
 
 
@@ -43,8 +44,8 @@ def control(cfg: dict, rows: int, seed: int) -> dict:
     return ref.control(rows, cfg["cols"], cfg["lam"], seed)
 
 
-def work(cfg: dict, rows: int) -> dict:
-    """The least work of one job: read ``[X, 1, y]`` once, write beta once.
+def work(cfg: dict, rows: int, traffic: dict) -> dict:
+    """The least work of one job, its one answer: read ``[X, 1, y]`` once, write beta once.
 
     The operand is ``(cols + 1) x rows`` float32 (the feature-major
     ``[X, 1, y]^T``). The operations are those of the Gram product that

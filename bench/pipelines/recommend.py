@@ -32,7 +32,8 @@ class Checker:
     def __init__(self, cfg: dict, rows: int, seed: int):
         self.regret = ref.Regret(rows, cfg["items"], cfg["density"], seed)
 
-    def __call__(self, got: np.ndarray) -> dict:
+    def __call__(self, got: np.ndarray, request: int = 0) -> dict:
+        """The numbers ``got`` is judged by; a job answers one request, 0."""
         return ref.compare(self.regret(got))
 
 
@@ -41,8 +42,8 @@ def control(cfg: dict, rows: int, seed: int) -> np.ndarray:
     return ref.control(rows, cfg["items"], cfg["density"], seed)
 
 
-def work(cfg: dict, rows: int) -> dict:
-    """The least work of one job: read R once, write one item per user.
+def work(cfg: dict, rows: int, traffic: dict) -> dict:
+    """The least work of one job, its one answer: read R once, write one item per user.
 
     R is ``rows x items`` float32. Per rating: a square and an add for the
     item norms, an add for the user mean, a divide, a subtract and a

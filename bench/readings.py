@@ -5,11 +5,14 @@
         --control-seeds 1 2 3 --seconds 10 --out readings.jsonl
 
 For each seed: the cell's set-up and a window of ``--seconds`` as
-``bench/run.py`` drives them, then every answer of the window compared
-with the float64 reference (the program's reading, the worst over the
-window's answers). For each control seed: the control, the reference put
-in the program's place one precision step below what the configuration
-states, compared with the same reference. Prints one JSON line per seed
+``bench/run.py`` drives them, with the same job (the pipeline's own
+``make_job`` where it has one), then every answer of the window compared
+with the plain reference by its tenant's Checker, through
+``bench/run.py``'s own ``run_window`` and ``check_answers`` (the
+program's reading, the worst over the window's answers). For each
+control seed: the control, the reference put in the program's place one
+precision step below what the configuration states, compared with the
+same reference. Prints one JSON line per seed
 and a last line with the lower reading (the largest of the program's) and
 the upper reading (the smallest of the control's) of each number. The
 benchmark's own runs never run the control.
@@ -32,11 +35,11 @@ def distinct(answers: list) -> int:
     """How many different answers the window gave, over all tenants."""
     import numpy as np
 
-    def key(tenant, answer):
+    def key(owed, answer):
         parts = answer.values() if isinstance(answer, dict) else [answer]
-        return (tenant,) + tuple(np.asarray(p).tobytes() for p in parts)
+        return (owed,) + tuple(np.asarray(p).tobytes() for p in parts)
 
-    return len({key(t, a) for t, a in answers})
+    return len({key(k, a) for k, a in answers})
 
 
 def readings(workload: str, seeds: list[int], control_seeds: list[int],
@@ -58,33 +61,21 @@ def readings(workload: str, seeds: list[int], control_seeds: list[int],
         rows, tenant_seeds = harness.tenants_of(cfg, traffic, seed)
         line = {"workload": workload, "seed": seed}
         answers = []
+        unanswered = 0
         if seed in seeds:
             lows = [pipeline.build(cfg, rows, s) for s in tenant_seeds]
-            job = harness.make_job(pipeline, lows, cfg["techniques"])
+            job = harness.job_of(pipeline, lows, cfg, traffic)
             job()
-            win = harness.run_window(job, seconds, [0])
+            win = harness.run_window(job, seconds, [0], harness.owed_of(pipeline, cfg, traffic))
             del job, lows
             gc.collect()
-            answers = win["answers"]
-            line.update(jobs=len(answers), errors=win["errors"][:3],
+            answers, unanswered = win["answers"], win["unanswered"]
+            line.update(jobs=len(answers), errors=win["errors"][:3], unanswered=unanswered,
                         distinct=distinct(answers))
-        worst_program: dict[str, float] = {}
-        worst_control: dict[str, float] = {}
-        failed = 0
-        for k, s in enumerate(tenant_seeds):
-            checker = pipeline.Checker(cfg, rows, s)  # one reference for both sides
-            for tenant, answer in answers:
-                if tenant == k:
-                    numbers = checker(answer)
-                    failed += any(not numbers[n] <= limits[n] for n in limits)
-                    worst_program = {n: max(worst_program.get(n, 0.0), v)
-                                     for n, v in numbers.items()}
-            if seed in control_seeds:
-                numbers = checker(pipeline.control(cfg, rows, s))
-                worst_control = {n: max(worst_control.get(n, 0.0), v)
-                                 for n, v in numbers.items()}
-            del checker
-            gc.collect()
+        failed, worst_program, worst_control = harness.check_answers(
+            pipeline, cfg, rows, tenant_seeds, answers, limits,
+            control=seed in control_seeds)
+        failed += unanswered
         if seed in seeds:
             line.update(program=worst_program, failed=failed)
             for n, v in worst_program.items():

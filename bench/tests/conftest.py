@@ -2,8 +2,8 @@
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests
 
-They sit outside the repository's ``testpaths``, so the repository's test
-run does not collect them.
+They are among the repository's ``testpaths``, so its test run collects
+them too.
 """
 
 import os

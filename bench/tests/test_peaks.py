@@ -8,16 +8,20 @@ import pytest
 from bench import peaks
 from bench.pipelines import linreg, recommend
 
-CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+BENCH = Path(__file__).resolve().parents[1]
 
 
 def config(name):
-    return json.loads((CONFIGS / f"{name}.json").read_text())
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def traffic(name):
+    return json.loads((BENCH / "traffic" / f"{name}.json").read_text())
 
 
 def test_higgs_linreg_counts():
     cfg = config("higgs-linreg")
-    work = linreg.work(cfg, cfg["rows"])
+    work = linreg.work(cfg, cfg["rows"], traffic("solo"))
     operand = 30 * 10_993_664 * 4  # [X, 1, y]^T in float32
     assert operand == 1_319_239_680  # 1,319.2 MB
     assert work["bytes"] == operand + 29 * 4  # and beta written once
@@ -28,7 +32,7 @@ def test_higgs_linreg_counts():
 
 def test_movielens_10m_counts():
     cfg = config("movielens-10m")
-    work = recommend.work(cfg, cfg["rows"])
+    work = recommend.work(cfg, cfg["rows"], traffic("solo"))
     ratings = 71_552 * 10_681 * 4
     assert ratings == 3_056_987_648  # 3,057.0 MB
     assert work["bytes"] == ratings + 71_552 * 4  # and one item per user
@@ -38,8 +42,8 @@ def test_movielens_10m_counts():
 
 def test_tenants_split_the_work():
     cfg = config("higgs-linreg")
-    eighth = linreg.work(cfg, cfg["rows"] // 8)
-    assert eighth["flops"] * 8 == linreg.work(cfg, cfg["rows"])["flops"]
+    eighth = linreg.work(cfg, cfg["rows"] // 8, traffic("tenants8"))
+    assert eighth["flops"] * 8 == linreg.work(cfg, cfg["rows"], traffic("solo"))["flops"]
 
 
 def test_unknown_device_kind_raises():

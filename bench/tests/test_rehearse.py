@@ -72,33 +72,72 @@ def test_without_the_program_exits_without_a_result(tmp_path):
     assert p.returncode != 0 and last_json(p.stdout) is None
 
 
-def test_a_new_cell_needs_only_new_files(tmp_path):
+def walker_cell(tmp_path):
+    """A cell of the linreg pipeline: two tenants of a tiny configuration, merged per launch."""
+    cfg = json.loads((ROOT / "bench/configs/higgs-linreg.json").read_text())
+    cfg.update(name="linreg-tiny", rows=4096, tile=512, rehearse={})
+    (tmp_path / "bench/traffic/tenants2.json").write_text(json.dumps(
+        {"tenants": 2, "split_rows": True, "warmup_jobs": 1,
+         "why": "two tenants of half the rows each"}))
+    (tmp_path / "bench/limits/linreg-tiny.tenants2.json").write_text(
+        (ROOT / "bench/limits/higgs-linreg.tenants8.json").read_text())
+    return cfg, "tenants2", 2
+
+
+def own_job_cell(tmp_path):
+    """A cell whose pipeline brings its own job: four requests a call, one matmul."""
+    shutil.copy(ROOT / "bench/tests/matmul_requests.py",
+                tmp_path / "bench/pipelines/matmul_requests.py")
+    cfg = {"name": "matmul-tiny", "source": "a test's configuration", "reduced": [],
+           "pipeline": "matmul_requests", "width": 64}
+    (tmp_path / "bench/traffic/requests4.json").write_text(json.dumps(
+        {"requests": 4, "arrival_gap_ms": 0.5, "warmup_jobs": 1,
+         "why": "four requests a call, arrived half a millisecond apart"}))
+    (tmp_path / "bench/limits/matmul-tiny.requests4.json").write_text(
+        json.dumps({"rel_err": 1e-4}))
+    return cfg, "requests4", 4
+
+
+@pytest.mark.parametrize("make_cell", [walker_cell, own_job_cell])
+def test_a_new_cell_needs_only_new_files(tmp_path, make_cell):
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (tmp_path / "src").symlink_to(ROOT / "src")
     before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*") if p.is_file()}
 
-    cfg = json.loads((ROOT / "bench/configs/higgs-linreg.json").read_text())
-    cfg.update(name="linreg-tiny", rows=4096, tile=512, rehearse={})
-    (tmp_path / "bench/configs/linreg-tiny.json").write_text(json.dumps(cfg))
-    (tmp_path / "bench/traffic/tenants2.json").write_text(json.dumps(
-        {"tenants": 2, "split_rows": True, "warmup_jobs": 1,
-         "why": "two tenants of half the rows each"}))
-    (tmp_path / "bench/limits/linreg-tiny.tenants2.json").write_text(
-        (ROOT / "bench/limits/higgs-linreg.tenants8.json").read_text())
+    cfg, traffic, owed = make_cell(tmp_path)
+    name = cfg["name"]
+    cell = f"{name}.{traffic}"
+    (tmp_path / f"bench/configs/{name}.json").write_text(json.dumps(cfg))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "linreg-tiny", "source": cfg["source"],
-                            "file": "bench/configs/linreg-tiny.json", "reduced": ["rows"],
+    spec["configs"].append({"name": name, "source": cfg["source"],
+                            "file": f"bench/configs/{name}.json", "reduced": cfg["reduced"],
                             "why": "a test's cell"})
-    spec["workloads"].append({"name": "linreg-tiny.tenants2", "config": "linreg-tiny",
-                              "traffic": "tenants2", "chips": 1, "why": "a test's cell"})
+    spec["workloads"].append({"name": cell, "config": name,
+                              "traffic": traffic, "chips": 1, "why": "a test's cell"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
 
-    p = bench(tmp_path, "--workload", "linreg-tiny.tenants2", "--seed", "5",
+    p = bench(tmp_path, "--workload", cell, "--seed", "5",
               "--seconds", "1", "--trace", "0", "--rehearse")
     assert p.returncode == 0, p.stderr[-2000:]
     result = last_json(p.stdout)
-    assert result["correct"] is True and result["attempted"] % 2 == 0
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 0 and result["attempted"] % owed == 0
+    assert result["window"]["jobs"] + result["window"]["traced_jobs"] == result["attempted"]
     after = {p: p.read_bytes() for p in before}
     assert after == before  # no file the benchmark had was edited
+
+
+def test_program_counters_over_the_window():
+    """The window's deltas of the program's exported counters: two concat outputs
+    of ``movielens-10m`` a job, each returned as the walker wrote it (one shard)."""
+    from bench import run as harness
+
+    result = harness.run(harness.parse_args(
+        ["--workload", "movielens-10m.solo", "--seed", "12", "--seconds", "1",
+         "--trace", "0", "--rehearse"]))
+    window = result["window"]
+    assert window["jobs"] > 0 and window["compiles"] == 0
+    assert window["counters"]["sched_walker_combine_taken"] == 2 * window["jobs"]
+    assert "sched_walker_combine_merged" not in window["counters"]  # a delta of 0

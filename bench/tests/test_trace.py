@@ -60,7 +60,7 @@ def test_recorded_chip_trace(recorded):
 def test_shares_stay_under_100_percent(recorded):
     devices, host, r = recorded
     jobs = sum(1 for name, _, _ in host if name == "bench.job")
-    work = linreg.work(HIGGS, HIGGS["rows"])
+    work = linreg.work(HIGGS, HIGGS["rows"], {"tenants": 1})
     least, bound = peaks.least_seconds(work["flops"], work["bytes"], "TPU v5 lite")
     assert bound == "memory"
     m = Measured(setup_s=0.0, window_s=r.window_s, jobs=jobs, latencies_s=[],
@@ -69,3 +69,29 @@ def test_shares_stay_under_100_percent(recorded):
         assert 0 < metric.read(m) <= 100
     assert walker_roofline.read(m) > job_mfu.read(m)
     assert walker_ms_per_job.read(m) > least * 1e3
+
+
+def test_span_time_per_host_event():
+    devices = {"/device:TPU:0": [("k", 0, 100)]}
+    host = [("bench.window", 10, 90), ("devdag.readback", 0, 20), ("devdag.readback", 40, 50),
+            ("devdag.launch", 30, 35), ("devdag.readback", 95, 99)]  # after the window
+    r = trace.reduce(devices, host, (10, 90))
+    assert r.span_counts == {"bench.window": 1, "devdag.readback": 2, "devdag.launch": 1}
+    assert r.span_seconds["devdag.readback"] == pytest.approx(20e-9)  # [10, 20) + [40, 50)
+    assert r.span_seconds["devdag.launch"] == pytest.approx(5e-9)
+
+
+def test_recorded_span_time(recorded):
+    devices, host, r = recorded
+    jobs = sum(1 for name, _, _ in host if name == "bench.job")
+    assert r.span_counts["bench.job"] == r.span_counts["bench.run_device_dag"] == jobs
+    assert 0 < r.span_seconds["bench.run_device_dag"] <= r.span_seconds["bench.job"] <= r.window_s
+
+
+def test_recorded_program_spans():
+    """A chip trace with the program's phases: one ``devdag.readback`` per job, of positive time."""
+    devices, host = trace.load(RECORDED.with_name("higgs-linreg.solo.spans.xplane.pb.gz"))
+    r = trace.reduce(devices, host, trace.window_of(host))
+    jobs = sum(1 for name, _, _ in host if name == "bench.job")
+    assert r.span_counts["devdag.readback"] == jobs > 0
+    assert 0 < r.span_seconds["devdag.readback"] < r.span_seconds["devdag.job"] <= r.window_s

@@ -15,14 +15,18 @@ checks it as well as a recorded trace:
   kernel's time is that of the operations whose name matches its pattern;
 * idle gaps: the stretches of the window in which the device ran nothing,
   each named by what the host was doing then: the shortest host event that
-  covers at least half of the gap.
+  covers at least half of the gap;
+* span time: the summed wall time and the number of each named host
+  event inside the window, so that the program's own phases (the
+  ``devdag.*`` annotations of ``repro.core.telemetry.phase``) and the
+  benchmark's ``bench.*`` ones are read from the same clock.
 """
 
 from __future__ import annotations
 
 import re
 from collections import defaultdict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")  # not e.g. /device:CUSTOM:...
@@ -45,6 +49,8 @@ class Reduction:
     op_counts: dict      # operation name -> number of events
     idle_gaps: list      # [[host event, seconds]], most first, at most ``top``
     top: int = 10
+    span_seconds: dict = field(default_factory=dict)  # host event name -> summed seconds
+    span_counts: dict = field(default_factory=dict)   # host event name -> events
 
     @property
     def idle_share(self) -> float:
@@ -157,13 +163,22 @@ def reduce(devices: dict[str, list[Event]], host: list[Event],
            window: tuple[int, int], top: int = 10) -> Reduction:
     """Reduce device operations and host events inside ``window`` (ns).
 
+    Host events are clipped to the window; one that straddles its edge
+    counts once, with the part inside.
+
     Devices without a single operation in the window are not among the
     devices used; a window in which no device ran anything raises.
     """
     lo, hi = window
     if hi <= lo:
         raise ValueError(f"empty window {window}")
-    label = _labeller(list(_clip(host, lo, hi)))
+    host = list(_clip(host, lo, hi))
+    label = _labeller(host)
+    span_ns: dict[str, int] = defaultdict(int)
+    span_counts: dict[str, int] = defaultdict(int)
+    for name, s, e in host:
+        span_ns[name] += e - s
+        span_counts[name] += 1
     busy_ns, used = 0, 0
     op_ns: dict[str, int] = defaultdict(int)
     op_counts: dict[str, int] = defaultdict(int)
@@ -186,7 +201,9 @@ def reduce(devices: dict[str, list[Event]], host: list[Event],
     return Reduction(window_s=(hi - lo) / 1e9, busy_s=busy_ns / used / 1e9,
                      op_seconds={k: v / 1e9 for k, v in op_ns.items()},
                      op_counts=dict(op_counts),
-                     idle_gaps=[[k, v / 1e9] for k, v in gaps], top=top)
+                     idle_gaps=[[k, v / 1e9] for k, v in gaps], top=top,
+                     span_seconds={k: v / 1e9 for k, v in span_ns.items()},
+                     span_counts=dict(span_counts))
 
 
 def reduce_file(path: str | Path) -> Reduction:
